@@ -118,8 +118,6 @@ class DryRunBackend(Backend):
         dt = time.perf_counter() - t0
         rep = StepReport(self.kind, prog.name, wall_s=dt)
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
         ma = compiled.memory_analysis()
         rep.memory = {
             "argument_bytes": float(getattr(ma, "argument_size_in_bytes", 0)),

@@ -14,7 +14,9 @@ Kernels:
                     d_ff intermediates never reach HBM.
   quantize        — int8 block quantization (gradient compression).
 
-Validated in interpret=True mode on CPU (the container rule: TPU is the
-TARGET, not the runtime); the dry-run XLA path never routes through
-Pallas so the 512-device lower/compile stays kernel-free.
+Every wrapper compiles for the TPU by default (``interpret=False``).
+The tests run each kernel with ``interpret=True`` on the CPU against its
+``ref.py`` oracle, and ``tests/test_tpu_compile.py`` compiles each one
+for a described TPU v5e chip at model widths.  No model path calls a
+kernel yet: the dry-run and the served/trained models are plain XLA.
 """

@@ -2,9 +2,8 @@
 
 ``flash_attention(q, k, v)`` takes model-layout (b, s, h, d) tensors,
 flattens (b, h) into the grid's leading axis, and dispatches to the
-Pallas kernel.  On this CPU container the kernel runs in interpret
-mode (assignment rule: TPU is the TARGET, interpret mode validates
-correctness); on TPU set ``interpret=False``.
+Pallas kernel, compiled for the TPU.  ``interpret=True`` runs the
+kernel in the Pallas interpreter instead (the CPU tests do).
 """
 
 from __future__ import annotations
@@ -21,7 +20,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_fwd
     "causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q/k/v: (b, s, h, d) -> (b, s, h, d)."""
     b, s, h, d = q.shape
     skv = k.shape[1]

@@ -21,26 +21,32 @@ def _quantize_kernel(x_ref, q_ref, s_ref):
     scale = jnp.maximum(jnp.max(jnp.abs(x), axis=1) / 127.0, 1e-12)
     q = jnp.clip(jnp.round(x / scale[:, None]), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale
+    s_ref[...] = scale[:, None]
 
 
 def quantize_fwd(x, *, block_rows: int = 64, interpret: bool = False):
-    """x: (nb, block) f32 -> (q (nb, block) int8, scales (nb,) f32)."""
+    """x: (nb, block) f32 -> (q (nb, block) int8, scales (nb,) f32).
+
+    The kernel writes the scales as an (nb, 1) column: a rank-1 block
+    of ``block_rows`` breaks the TPU rule that a rank-1 block is a
+    multiple of 128 or the whole array.
+    """
     nb, block = x.shape
     block_rows = min(block_rows, nb)
     assert nb % block_rows == 0
     grid = (nb // block_rows,)
-    return pl.pallas_call(
+    q, scales = pl.pallas_call(
         _quantize_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, block), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((block_rows, block), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb, block), jnp.int8),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
+    return q, scales[:, 0]

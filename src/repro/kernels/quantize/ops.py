@@ -13,7 +13,7 @@ from repro.kernels.quantize.kernel import quantize_fwd
 @functools.partial(jax.jit, static_argnames=("block", "block_rows",
                                              "interpret"))
 def quantize(x, *, block: int = 256, block_rows: int = 64,
-             interpret: bool = True):
+             interpret: bool = False):
     """x: any-shape f32 -> (q (nb, block) int8, scales (nb,), pad)."""
     flat = x.astype(jnp.float32).reshape(-1)
     pad = (-flat.size) % block

@@ -8,7 +8,8 @@ pattern).
 
 Per chunk of length L (default 64) with head size n (= 64 for RWKV6):
   load r/k/v/logw tiles (L, n) -> VMEM,
-  cum = cumsum(logw) along L,
+  cum = cumsum(logw) along L, as a lower-triangular (L, L) matmul
+  (Mosaic has no cumsum),
   pairwise decay D[l, m] = exp(cum[l-1] - cum[m]) masked to m < l
   (every exponent <= 0: numerically safe by construction),
   intra = (r*exp(cum_prev)) @ state  +  ((r (x) k (x) D) @ v  + diag-u,
@@ -16,12 +17,13 @@ Per chunk of length L (default 64) with head size n (= 64 for RWKV6):
 
 Working set: 4 tiles (L, n) + state (n, n) f32 + the (L, L) score tile
 ~ 64KB << VMEM.  The MXU sees (L, n) x (n, n) and (L, L) x (L, n)
-matmuls; the decay einsum is VPU work of the same element count as one
-matmul.
+matmuls; the decay-weighted scores are summed one head channel at a
+time as (L, L) VPU work, so the (L, L, n) pairwise-decay tensor is
+never built.
 
 The HBM win vs the jnp path: r/k/v/w are read once and y written once
-per chunk — the (L, L, n) pairwise-decay tensor never leaves VMEM
-(it dominates the jnp path's memory traffic at rwkv6-7b scale).
+per chunk — the pairwise-decay tensor never reaches HBM (it dominates
+the jnp path's memory traffic at rwkv6-7b scale).
 """
 
 from __future__ import annotations
@@ -32,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_ref, *,
@@ -48,30 +52,41 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_ref, *,
     v = v_ref[0].astype(f32)
     lw = lw_ref[0].astype(f32)         # log decay, <= 0
     u = u_ref[0].astype(f32)           # (1, n) bonus for this head
+    n = r.shape[1]
 
-    cum = jnp.cumsum(lw, axis=0)       # (L, n)
+    def mm(a, b, contract=((1,), (0,))):
+        return jax.lax.dot_general(a, b, (contract, ((), ())),
+                                   precision=_HIGHEST)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive cumsum along L as a lower-triangular matmul
+    cum = mm((col <= row).astype(f32), lw)                 # (L, n)
     cum_prev = cum - lw
     S = s_ref[...]                     # (n, n)
 
     # inter-chunk: r_t * a_{t-1} applied to the carried state
-    r_hat = r * jnp.exp(cum_prev)
-    inter = jax.lax.dot_general(r_hat, S, (((1,), (0,)), ((), ())))
+    inter = mm(r * jnp.exp(cum_prev), S)
 
-    # intra-chunk pairwise: D[l,m,n] = exp(cum_prev[l]-cum[m]) for m<l
-    dmat = cum_prev[:, None, :] - cum[None, :, :]          # (L, L, n)
-    causal = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1) \
-        < jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    dmat = jnp.where(causal[:, :, None], dmat, -jnp.inf)
-    scores = jnp.einsum("ln,mn,lmn->lm", r, k, jnp.exp(dmat))
-    intra = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())))
+    # intra-chunk pairwise scores, one head channel at a time:
+    # scores[l,m] = sum_c r[l,c] k[m,c] exp(cum_prev[l,c] - cum[m,c]), m < l
+    # (static slices only: Mosaic implements no dynamic_slice)
+    causal = col < row
+    k_t = k.T                          # (n, L)
+    cum_t = cum.T
+    scores = jnp.zeros((chunk, chunk), f32)
+    for c in range(n):
+        d = cum_prev[:, c:c + 1] - cum_t[c:c + 1, :]        # (L, L)
+        d = jnp.where(causal, d, -jnp.inf)
+        scores = scores + (r[:, c:c + 1] * k_t[c:c + 1, :]) * jnp.exp(d)
+    intra = mm(scores, v)
     diag = jnp.sum(r * u * k, axis=1, keepdims=True)       # (L, 1)
     y = inter + intra + diag * v
 
-    # state update (all multipliers <= 1)
-    a_L = jnp.exp(cum[-1])                                  # (n,)
-    k_tail = k * jnp.exp(cum[-1:, :] - cum)                 # (L, n)
-    s_ref[...] = a_L[:, None] * S + jax.lax.dot_general(
-        k_tail, v, (((0,), (0,)), ((), ())))
+    # state update (all multipliers <= 1); total == cum[L-1]
+    total = jnp.sum(lw, axis=0, keepdims=True)             # (1, n)
+    k_tail = k * jnp.exp(total - cum)                       # (L, n)
+    s_ref[...] = jnp.exp(total).T * S + mm(k_tail, v, ((0,), (0,)))
     y_ref[0] = y.astype(y_ref.dtype)
 
 
@@ -79,7 +94,8 @@ def wkv6_fwd(r, k, v, lw, u, *, chunk: int = 64, interpret: bool = False):
     """r/k/v/lw: (bh, s, n); u: (bh, n).  Returns y (bh, s, n).
 
     lw = log(decay) (<= 0).  bh = batch*heads; u is per-head, callers
-    broadcast it to (bh, n).
+    broadcast it to (bh, n).  u enters the kernel as (bh, 1, n) so its
+    block's last two dims equal the array's (the TPU tiling rule).
     """
     bh, s, n = r.shape
     chunk = min(chunk, s)
@@ -94,10 +110,10 @@ def wkv6_fwd(r, k, v, lw, u, *, chunk: int = 64, interpret: bool = False):
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, n), lambda b, c: (b, 0)),
+            pl.BlockSpec((1, 1, n), lambda b, c: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, n), r.dtype),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, lw, u)
+    )(r, k, v, lw, u[:, None, :])

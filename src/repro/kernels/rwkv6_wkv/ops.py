@@ -11,7 +11,7 @@ from repro.kernels.rwkv6_wkv.kernel import wkv6_fwd
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def wkv6(r, k, v, w, u, *, chunk: int = 64, interpret: bool = False):
     """r/k/v/w: (b, s, h, n) with w = decay in (0,1); u: (h, n)."""
     b, s, h, n = r.shape
     lw = jnp.log(jnp.maximum(w.astype(jnp.float32), 1e-38))

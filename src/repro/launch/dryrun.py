@@ -38,10 +38,11 @@ from repro.configs import (REGISTRY, SHAPES, cell_runnable, get_config,
 from repro.core.desim.hlo_cost import analyze_hlo
 from repro.dist.sharding import MeshSharder, make_rules
 from repro.launch.mesh import describe, make_production_mesh
+from repro.launch.train import build_sharded_train
 from repro.models import build_model
 from repro.serve.step import build_decode_step, build_prefill_step
-from repro.train.step import (TrainOptions, build_train_step,
-                              default_options_for, train_state_specs)
+from repro.train.step import (TrainOptions, default_options_for,
+                              train_state_specs)
 
 # TPU v5e per-chip constants (assignment-specified)
 PEAK_FLOPS = 197e12
@@ -126,15 +127,9 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
     t0 = time.perf_counter()
     if shape.kind == "train":
-        state_shapes, state_axes = train_state_specs(model, opts)
-        step = build_train_step(model, opts, sharder,
-                                param_axes=state_axes["params"])
-        state_sh = sharder.param_shardings(state_axes)
-        batch_specs = model.input_specs(shape)
-        batch_sh = sharder.batch_shardings(batch_specs, cfg)
-        fn = jax.jit(step, in_shardings=(state_sh, batch_sh),
-                     donate_argnums=(0,))
-        args = (state_shapes, batch_specs)
+        state_shapes, _ = train_state_specs(model, opts)
+        fn, _, _ = build_sharded_train(model, opts, sharder, shape)
+        args = (state_shapes, model.input_specs(shape))
     elif shape.kind == "prefill":
         pstep = build_prefill_step(model, sharder, chunk=opts.chunk,
                                    seq_capacity=shape.seq_len)
@@ -179,8 +174,6 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool = False,
     mem["per_device_total"] = (mem["argument_bytes"] + mem["output_bytes"]
                                + mem["temp_bytes"] - mem["alias_bytes"])
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     cost = analyze_hlo(hlo)
     rt = roofline_terms(cost, n_dev)

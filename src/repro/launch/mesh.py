@@ -24,13 +24,8 @@ import jax
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    # axis_types only exists on newer jax; Auto is the default there, so
-    # omitting it on older versions is behaviourally identical.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

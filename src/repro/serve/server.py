@@ -1,5 +1,4 @@
-"""Continuous-batching inference server (vLLM-style slot scheduler,
-CPU-scale).
+"""Continuous-batching inference server (vLLM-style slot scheduler).
 
 A fixed decode batch of B slots; requests from a queue are prefilled
 one at a time (B=1 prefill) and their caches inserted into free slots;
@@ -61,6 +60,46 @@ class BatchServer(SimObject):
                                                 1))
 
     # ------------------------------------------------------------------
+    def _prefill_into(self, cache, slot: int, req: Request):
+        """Prefill ``req`` alone (B=1) and copy its cache into ``slot``.
+        Returns (cache, last-position logits (Vp,))."""
+        batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32),
+                 **{k: jnp.asarray(v)[None] for k, v in req.extras.items()}}
+        logits, rcache = self._prefill(self.params, batch)
+        cache = jax.tree.map(
+            lambda c, rc: jax.lax.dynamic_update_slice_in_dim(
+                c, rc.astype(c.dtype), slot, 1),
+            cache, rcache)
+        return cache, logits[0, -1]
+
+    def replay_logits(self, req: Request) -> np.ndarray:
+        """Logits of a served request through this server's own steps.
+
+        Replays ``req.prompt`` and ``req.output`` alone in slot 0 of a
+        fresh cache.  Row t (f32, shape (Vp,)) is what the prefill
+        (t = 0) or the t-th decode step computes for the token after
+        ``prompt + output[:t]``, so a full forward pass over
+        ``prompt + output[:-1]`` must give the same rows.
+        """
+        B = self.slots
+        cache = self.model.init_cache(B, self.seq_capacity)
+        cache, row = self._prefill_into(cache, 0, req)
+        rows = [row]
+        for i, t in enumerate(req.output[:-1]):
+            # fresh host arrays each step: the CPU backend may alias a
+            # numpy input until the (asynchronous) step has read it
+            tok = np.zeros((B, 1), np.int32)
+            tok[0, 0] = t
+            cur_len = np.zeros((B,), np.int32)
+            cur_len[0] = len(req.prompt) + i
+            _, logits, cache = self._decode(self.params, {
+                "tokens": jnp.asarray(tok),
+                "cache": cache,
+                "cur_len": jnp.asarray(cur_len),
+            })
+            rows.append(logits[0, -1])
+        return np.asarray(jnp.stack(rows).astype(jnp.float32))
+
     def serve(self, requests: List[Request]) -> List[Request]:
         """Serve ``requests`` to completion.
 
@@ -89,17 +128,10 @@ class BatchServer(SimObject):
         done: List[Request] = []
 
         def insert(slot: int, req: Request) -> None:
-            batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32),
-                     **{k: jnp.asarray(v)[None] for k, v in
-                        req.extras.items()}}
-            logits, rcache = self._prefill(self.params, batch)
             nonlocal cache
-            cache = jax.tree.map(
-                lambda c, rc: jax.lax.dynamic_update_slice_in_dim(
-                    c, rc.astype(c.dtype), slot, 1),
-                cache, rcache)
+            cache, logits = self._prefill_into(cache, slot, req)
             tok = int(jax.device_get(jnp.argmax(
-                logits[0, -1].astype(jnp.float32))))
+                logits.astype(jnp.float32))))
             req.output.append(tok)
             last_tok[slot, 0] = tok
             cur_len[slot] = len(req.prompt)

@@ -60,6 +60,9 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# every tier runs on the CPU, also on a machine with a TPU: the chip is
+# reached only through chip_smoke.py
+export JAX_PLATFORMS=cpu
 if [ "${1-}" = "golden" ]; then
   shift
   python -m pytest -q tests/test_golden_stats.py "$@"
