@@ -7,6 +7,27 @@ single batched decode step (per-slot ``cur_len`` vector).  Finished
 slots (max tokens or EOS) are freed.  The server is a SimObject with
 throughput/latency stats — and the DES can model the same policy at pod
 scale for the dse_sweep benchmark.
+
+Each loop iteration is marked with ``jax.profiler`` spans, recorded only
+while a profiler session is active (``jax.profiler.trace``), on the host
+thread that runs the loop.  A profile then shows, for every stretch in
+which the device sat idle, which part of the loop the host was in:
+
+- ``serve.step``: one iteration, as the profiler's step
+  (``step_num`` = the server's decode-step count before the step);
+- ``serve.fill``: admitting queued requests into free slots, holding a
+  ``serve.prefill`` span per request (``rid``, ``prompt_len``): the
+  prefill, the cache insert and the read-back of the first token;
+- ``serve.dispatch``: the step's inputs put on the device and the
+  batched decode step called (it runs asynchronously);
+- ``serve.sync``: waiting for the step's tokens to reach the host;
+- ``serve.emit``: appending the tokens to the requests and retiring
+  finished ones.
+
+The stats ``sync_wait`` and ``loop_host`` split every iteration into the
+seconds spent in ``serve.sync`` and the rest; ``queue_wait`` is each
+request's time from ``serve`` being called to its slot insert.  They are
+kept whether or not a profiler runs.
 """
 
 from __future__ import annotations
@@ -24,6 +45,13 @@ from repro.models.api import Model
 from repro.serve.policy import SlotScheduler
 from repro.serve.step import build_decode_step, build_prefill_step
 
+SPAN_STEP = "serve.step"
+SPAN_FILL = "serve.fill"
+SPAN_PREFILL = "serve.prefill"
+SPAN_DISPATCH = "serve.dispatch"
+SPAN_SYNC = "serve.sync"
+SPAN_EMIT = "serve.emit"
+
 
 @dataclass
 class Request:
@@ -35,6 +63,7 @@ class Request:
     # filled by the server:
     output: List[int] = field(default_factory=list)
     submit_time: float = 0.0
+    insert_time: float = 0.0
     finish_time: float = 0.0
 
 
@@ -54,6 +83,12 @@ class BatchServer(SimObject):
         self.s_requests = self.stats.scalar("requests", "requests served")
         self.s_latency = self.stats.distribution("latency", unit="s")
         self.s_decode_steps = self.stats.scalar("decode_steps")
+        self.s_sync_wait = self.stats.distribution(
+            "sync_wait", "waiting for a decode step's tokens", unit="s")
+        self.s_loop_host = self.stats.distribution(
+            "loop_host", "rest of a loop iteration", unit="s")
+        self.s_queue_wait = self.stats.distribution(
+            "queue_wait", "submit to slot insert, per request", unit="s")
         self.s_throughput = self.stats.formula(
             "tokens_per_decode_step",
             lambda: self.s_tokens.value() / max(self.s_decode_steps.value(),
@@ -129,36 +164,53 @@ class BatchServer(SimObject):
 
         def insert(slot: int, req: Request) -> None:
             nonlocal cache
-            cache, logits = self._prefill_into(cache, slot, req)
-            tok = int(jax.device_get(jnp.argmax(
-                logits.astype(jnp.float32))))
-            req.output.append(tok)
-            last_tok[slot, 0] = tok
-            cur_len[slot] = len(req.prompt)
+            with jax.profiler.TraceAnnotation(
+                    SPAN_PREFILL, rid=req.rid, prompt_len=len(req.prompt)):
+                req.insert_time = time.perf_counter()
+                cache, logits = self._prefill_into(cache, slot, req)
+                tok = int(jax.device_get(jnp.argmax(
+                    logits.astype(jnp.float32))))
+                req.output.append(tok)
+                last_tok[slot, 0] = tok
+                cur_len[slot] = len(req.prompt)
+            self.s_queue_wait.sample(req.insert_time - req.submit_time)
 
         while not sched.idle():
-            # fill free slots (prefill emits each request's first token)
-            for slot, rid in sched.fill():
-                insert(slot, by_rid[rid])
-            # one batched decode step for all active slots
-            nxt, _, cache = self._decode(self.params, {
-                "tokens": jnp.asarray(last_tok),
-                "cache": cache,
-                "cur_len": jnp.asarray(cur_len),
-            })
-            nxt = np.asarray(jax.device_get(nxt))
-            self.s_decode_steps.inc()
-            sched.note_step()
-            for slot in sched.active_slots():
-                req = by_rid[sched.active[slot]]
-                tok = int(nxt[slot, 0])
-                req.output.append(tok)
-                self.s_tokens.inc()
-                cur_len[slot] += 1
-                last_tok[slot, 0] = tok
-                if sched.complete_token(slot, is_eos=tok == req.eos_token):
-                    req.finish_time = time.perf_counter()
-                    self.s_requests.inc()
-                    self.s_latency.sample(req.finish_time - req.submit_time)
-                    done.append(req)
+            t_step = time.perf_counter()
+            with jax.profiler.StepTraceAnnotation(
+                    SPAN_STEP, step_num=int(self.s_decode_steps.value())):
+                # fill free slots (prefill emits each request's first token)
+                with jax.profiler.TraceAnnotation(SPAN_FILL):
+                    for slot, rid in sched.fill():
+                        insert(slot, by_rid[rid])
+                # one batched decode step for all active slots
+                with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+                    nxt, _, cache = self._decode(self.params, {
+                        "tokens": jnp.asarray(last_tok),
+                        "cache": cache,
+                        "cur_len": jnp.asarray(cur_len),
+                    })
+                with jax.profiler.TraceAnnotation(SPAN_SYNC):
+                    t_sync = time.perf_counter()
+                    nxt = np.asarray(jax.device_get(nxt))
+                    sync = time.perf_counter() - t_sync
+                self.s_decode_steps.inc()
+                sched.note_step()
+                with jax.profiler.TraceAnnotation(SPAN_EMIT):
+                    for slot in sched.active_slots():
+                        req = by_rid[sched.active[slot]]
+                        tok = int(nxt[slot, 0])
+                        req.output.append(tok)
+                        self.s_tokens.inc()
+                        cur_len[slot] += 1
+                        last_tok[slot, 0] = tok
+                        if sched.complete_token(slot,
+                                                is_eos=tok == req.eos_token):
+                            req.finish_time = time.perf_counter()
+                            self.s_requests.inc()
+                            self.s_latency.sample(
+                                req.finish_time - req.submit_time)
+                            done.append(req)
+            self.s_sync_wait.sample(sync)
+            self.s_loop_host.sample(time.perf_counter() - t_step - sync)
         return done

@@ -6,6 +6,21 @@ stats group (loss, step-time distribution, straggler count, checkpoint
 count) into the system tree.  Fault injection for tests: pass
 ``fail_at={step: exception}`` and the trainer demonstrates
 checkpoint-restore recovery.
+
+Each step is marked with ``jax.profiler`` spans, recorded only while a
+profiler session is active (``jax.profiler.trace``), on the host thread
+that runs the step:
+
+- ``train.step``: the whole step, as the profiler's step
+  (``step_num`` = the step);
+- ``train.input``: the pipeline building the step's batch on the host
+  and the batch put on the device;
+- ``train.dispatch``: the call of the jitted step (it runs
+  asynchronously);
+- ``train.sync``: waiting for the step's loss to reach the host.
+
+The stat ``input_time`` (and each history row's ``input_s``) holds the
+seconds spent in ``train.input``, whether or not a profiler runs.
 """
 
 from __future__ import annotations
@@ -21,6 +36,11 @@ from repro.data.pipeline import SyntheticPipeline
 from repro.train.ft import Heartbeat, StragglerWatchdog
 from repro.train.ft_policy import (FailureSchedule, FTPolicy,
                                    checkpoint_due)
+
+SPAN_STEP = "train.step"
+SPAN_INPUT = "train.input"
+SPAN_DISPATCH = "train.dispatch"
+SPAN_SYNC = "train.sync"
 
 
 class SimulatedFailure(RuntimeError):
@@ -53,6 +73,8 @@ class Trainer(SimObject):
         self.s_stalls = self.stats.scalar("stalls",
                                           "attempts hung on a silent pod")
         self.s_step_time = self.stats.distribution("step_time", unit="s")
+        self.s_input_time = self.stats.distribution(
+            "input_time", "building and placing a step's batch", unit="s")
         self.history: list = []
 
     # ------------------------------------------------------------------
@@ -60,20 +82,28 @@ class Trainer(SimObject):
         """One real training step with all its bookkeeping (stats,
         watchdog, history, heartbeat) — the single copy both ``run``
         and ``run_ft`` execute."""
-        batch = {k: jax.numpy.asarray(v)
-                 for k, v in self.pipeline.batch(step).items()}
-        t0 = time.perf_counter()
-        self.state, metrics = self._jitted(self.state, batch)
-        loss = float(jax.device_get(metrics["loss"]))
-        dt = time.perf_counter() - t0
-        if self.watchdog.record(step, dt):
-            self.s_stragglers.inc()
-        self.s_step_time.sample(dt)
-        self.s_loss.set(loss)
-        self.s_steps.inc()
-        self.history.append({"step": step, "loss": loss, "time_s": dt})
-        if self.heartbeat:
-            self.heartbeat.beat(step)
+        with jax.profiler.StepTraceAnnotation(SPAN_STEP, step_num=step):
+            with jax.profiler.TraceAnnotation(SPAN_INPUT):
+                t_in = time.perf_counter()
+                batch = {k: jax.numpy.asarray(v)
+                         for k, v in self.pipeline.batch(step).items()}
+                input_s = time.perf_counter() - t_in
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+                self.state, metrics = self._jitted(self.state, batch)
+            with jax.profiler.TraceAnnotation(SPAN_SYNC):
+                loss = float(jax.device_get(metrics["loss"]))
+            dt = time.perf_counter() - t0
+            if self.watchdog.record(step, dt):
+                self.s_stragglers.inc()
+            self.s_step_time.sample(dt)
+            self.s_input_time.sample(input_s)
+            self.s_loss.set(loss)
+            self.s_steps.inc()
+            self.history.append({"step": step, "loss": loss, "time_s": dt,
+                                 "input_s": input_s})
+            if self.heartbeat:
+                self.heartbeat.beat(step)
 
     def run(self, num_steps: int,
             fail_at: Optional[Dict[int, Exception]] = None) -> Dict:
