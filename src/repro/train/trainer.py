@@ -7,26 +7,44 @@ count) into the system tree.  Fault injection for tests: pass
 ``fail_at={step: exception}`` and the trainer demonstrates
 checkpoint-restore recovery.
 
+The batch of the next step is built ahead, on one worker thread, while
+the current step runs on the device: once step k is dispatched, the
+worker runs ``pipeline.batch(k + 1)`` and puts it on the device.  Step
+k + 1 takes that batch if the step it is about to run is the one the
+worker built, and otherwise (the first step, a restore or rewind)
+builds its own as before.  ``batch(step)`` is a pure function of
+``(seed, step)``, so the bits are the same either way.  The prefetch
+lives across calls to ``run``; an exception in the worker is raised by
+the step that takes its batch, and a batch no step takes is dropped.
+
 Each step is marked with ``jax.profiler`` spans, recorded only while a
 profiler session is active (``jax.profiler.trace``), on the host thread
 that runs the step:
 
 - ``train.step``: the whole step, as the profiler's step
   (``step_num`` = the step);
-- ``train.input``: the pipeline building the step's batch on the host
-  and the batch put on the device;
+- ``train.input``: waiting for the step's batch on the device: for the
+  worker's, or building it here where the worker built another step's;
 - ``train.dispatch``: the call of the jitted step (it runs
   asynchronously);
 - ``train.sync``: waiting for the step's loss to reach the host.
 
+and on the worker's thread:
+
+- ``train.prefetch``: building a batch ahead and putting it on the
+  device (``step_num`` = the step it is for).
+
 The stat ``input_time`` (and each history row's ``input_s``) holds the
-seconds spent in ``train.input``, whether or not a profiler runs.
+seconds spent in ``train.input``, whether or not a profiler runs;
+``input_prefetched`` counts the steps whose batch the worker built
+(each history row's ``prefetched``).
 """
 
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, Dict, Optional
+from concurrent.futures import Future, ThreadPoolExecutor
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
@@ -41,6 +59,7 @@ SPAN_STEP = "train.step"
 SPAN_INPUT = "train.input"
 SPAN_DISPATCH = "train.dispatch"
 SPAN_SYNC = "train.sync"
+SPAN_PREFETCH = "train.prefetch"
 
 
 class SimulatedFailure(RuntimeError):
@@ -65,6 +84,11 @@ class Trainer(SimObject):
         self.watchdog = StragglerWatchdog()
         self.heartbeat = Heartbeat(heartbeat_path) if heartbeat_path else None
         self._jitted = jax.jit(train_step, donate_argnums=(0,))
+        # builds the next step's batch; its thread starts on the first
+        # prefetch and ends once the trainer is collected
+        self._worker = ThreadPoolExecutor(
+            1, thread_name_prefix="trainer-prefetch")
+        self._ahead: Optional[Tuple[int, Future]] = None
         # stats
         self.s_loss = self.stats.scalar("loss", "last loss")
         self.s_steps = self.stats.scalar("steps", "steps completed")
@@ -74,10 +98,40 @@ class Trainer(SimObject):
                                           "attempts hung on a silent pod")
         self.s_step_time = self.stats.distribution("step_time", unit="s")
         self.s_input_time = self.stats.distribution(
-            "input_time", "building and placing a step's batch", unit="s")
+            "input_time", "waiting for a step's batch on the device",
+            unit="s")
+        self.s_prefetched = self.stats.scalar(
+            "input_prefetched", "steps whose batch the worker built ahead")
         self.history: list = []
 
     # ------------------------------------------------------------------
+    def _build_batch(self, step: int) -> Dict[str, jax.Array]:
+        return {k: jax.numpy.asarray(v)
+                for k, v in self.pipeline.batch(step).items()}
+
+    def _prefetch_batch(self, step: int) -> Dict[str, jax.Array]:
+        with jax.profiler.TraceAnnotation(SPAN_PREFETCH, step_num=step):
+            return self._build_batch(step)
+
+    def _take_batch(self, step: int) -> Tuple[Dict[str, jax.Array], bool]:
+        """``step``'s batch, and whether the worker built it: the
+        worker's if it built this step's (raising what it raised), else
+        one built here."""
+        ahead = self._ahead
+        if ahead is not None and ahead[0] == step:
+            self._ahead = None
+            return ahead[1].result(), True
+        return self._build_batch(step), False
+
+    def _prefetch(self, step: int) -> None:
+        """Start building ``step``'s batch on the worker, unless it is
+        still building one that no step took: at most one batch is ever
+        in flight."""
+        ahead = self._ahead
+        if ahead is not None and not (ahead[1].done() or ahead[1].cancel()):
+            return
+        self._ahead = (step, self._worker.submit(self._prefetch_batch, step))
+
     def _run_one_step(self, step: int) -> None:
         """One real training step with all its bookkeeping (stats,
         watchdog, history, heartbeat) — the single copy both ``run``
@@ -85,12 +139,12 @@ class Trainer(SimObject):
         with jax.profiler.StepTraceAnnotation(SPAN_STEP, step_num=step):
             with jax.profiler.TraceAnnotation(SPAN_INPUT):
                 t_in = time.perf_counter()
-                batch = {k: jax.numpy.asarray(v)
-                         for k, v in self.pipeline.batch(step).items()}
+                batch, prefetched = self._take_batch(step)
                 input_s = time.perf_counter() - t_in
             t0 = time.perf_counter()
             with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
                 self.state, metrics = self._jitted(self.state, batch)
+            self._prefetch(step + 1)
             with jax.profiler.TraceAnnotation(SPAN_SYNC):
                 loss = float(jax.device_get(metrics["loss"]))
             dt = time.perf_counter() - t0
@@ -98,10 +152,13 @@ class Trainer(SimObject):
                 self.s_stragglers.inc()
             self.s_step_time.sample(dt)
             self.s_input_time.sample(input_s)
+            if prefetched:
+                self.s_prefetched.inc()
             self.s_loss.set(loss)
             self.s_steps.inc()
             self.history.append({"step": step, "loss": loss, "time_s": dt,
-                                 "input_s": input_s})
+                                 "input_s": input_s,
+                                 "prefetched": prefetched})
             if self.heartbeat:
                 self.heartbeat.beat(step)
 
@@ -126,9 +183,11 @@ class Trainer(SimObject):
                 retries += 1
                 if retries > self.max_retries:
                     raise
-                if self.ckpt and self.ckpt.latest_step() is not None:
-                    self.state = self.ckpt.restore(self.state)
-                    step = int(jax.device_get(self.state["step"]))
+                if self.ckpt:
+                    self.ckpt.wait()    # a save still being written counts
+                    if self.ckpt.latest_step() is not None:
+                        self.state = self.ckpt.restore(self.state)
+                        step = int(jax.device_get(self.state["step"]))
                 # else: continue from in-memory state (lost step)
         if self.ckpt:
             self.ckpt.save(self.state, step)

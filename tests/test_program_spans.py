@@ -26,7 +26,8 @@ from repro.train import trainer as T
 
 SERVE_SPANS = (S.SPAN_STEP, S.SPAN_FILL, S.SPAN_PREFILL, S.SPAN_DISPATCH,
                S.SPAN_SYNC, S.SPAN_EMIT)
-TRAIN_SPANS = (T.SPAN_STEP, T.SPAN_INPUT, T.SPAN_DISPATCH, T.SPAN_SYNC)
+TRAIN_SPANS = (T.SPAN_STEP, T.SPAN_INPUT, T.SPAN_DISPATCH, T.SPAN_SYNC,
+               T.SPAN_PREFETCH)
 TRAIN_STEPS = 3
 
 
@@ -43,7 +44,8 @@ class Span:
 
 def host_spans(trace_dir, names):
     """The host events named in ``names``, in start order, with the
-    line each lies on."""
+    line each lies on (by index: a plane's thread lines can share a
+    name)."""
     files = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
                       recursive=True)
     assert files, "the profiler wrote no trace"
@@ -52,9 +54,9 @@ def host_spans(trace_dir, names):
                                            key=os.path.getmtime)).planes:
         if not plane.name.startswith("/host:"):
             continue
-        for line in plane.lines:
-            out += [Span(ev, (plane.name, line.name)) for ev in line.events
-                    if ev.name in names]
+        for i, line in enumerate(plane.lines):
+            out += [Span(ev, (plane.name, i, line.name))
+                    for ev in line.events if ev.name in names]
     return sorted(out, key=lambda s: (s.start, -s.end))
 
 
@@ -193,7 +195,14 @@ def test_train_spans_nest_as_the_step_runs(trained):
         assert [p.name for p in phases] == [T.SPAN_INPUT, T.SPAN_DISPATCH,
                                             T.SPAN_SYNC]
         assert all(a.end <= b.start for a, b in zip(phases, phases[1:]))
-    assert len(spans) == 4 * TRAIN_STEPS
+    # the worker builds steps 1.. ahead, on a thread of its own; the last
+    # may still be in flight when the profiler stops
+    ahead = [s for s in spans if s.name == T.SPAN_PREFETCH]
+    assert [s.stats["step_num"] for s in ahead] == \
+        list(range(1, len(ahead) + 1))
+    assert len(ahead) in (TRAIN_STEPS - 1, TRAIN_STEPS)
+    assert all(s.line != steps[0].line for s in ahead)
+    assert len(spans) == 4 * TRAIN_STEPS + len(ahead)
 
 
 def test_train_losses_identical_with_profiler_on(trained):
@@ -210,4 +219,5 @@ def test_train_input_counter_counts_steps(trained):
     assert tr.s_input_time.count == tr.s_steps.value() == TRAIN_STEPS
     inputs = [h["input_s"] for h in tr.history]
     assert len(inputs) == TRAIN_STEPS and min(inputs) > 0.0
+    assert tr.s_prefetched.value() == TRAIN_STEPS - 1
     assert tr.s_input_time.mean == pytest.approx(np.mean(inputs))
