@@ -23,11 +23,12 @@ from repro.configs.qwen2_vl_7b import CONFIG as _qwen2vl
 from repro.configs.rwkv6_7b import CONFIG as _rwkv6
 from repro.configs.jamba_v0_1_52b import CONFIG as _jamba
 from repro.configs.whisper_small import CONFIG as _whisper
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite
 
 REGISTRY: Dict[str, ArchConfig] = {
     c.name: c for c in (
         _olmoe, _mixtral, _stablelm, _deepseek, _minicpm, _nemotron,
-        _qwen2vl, _rwkv6, _jamba, _whisper,
+        _qwen2vl, _rwkv6, _jamba, _whisper, _granite,
     )
 }
 

@@ -57,9 +57,14 @@ class ArchConfig:
     norm_eps: float = 1e-5
 
     # --- SSM (mamba / rwkv) ----------------------------------------------
-    d_state: int = 16           # mamba state per channel
+    ssm_kind: str = "mamba1"    # a hybrid's state-space layers: mamba1|mamba2
+    d_state: int = 16           # mamba state per channel (mamba2: per row)
     d_conv: int = 4             # mamba local conv taps
     expand: int = 2             # mamba d_inner = expand * d_model
+    ssm_heads: int = 0          # mamba2 heads (x ssm_head_dim = d_inner)
+    ssm_head_dim: int = 0       # mamba2 channels per head
+    ssm_groups: int = 1         # mamba2 B/C groups shared by the heads
+    ssm_chunk: int = 256        # mamba2 chunk of the SSD prefill/train form
     rwkv_head_size: int = 64
 
     # --- hybrid (jamba) ---------------------------------------------------
@@ -73,6 +78,11 @@ class ArchConfig:
 
     tie_embeddings: bool = False
     residual_scale: float = 1.0  # minicpm depth-scaled residual
+
+    # --- muP-style multipliers (1 / 0 = none) ------------------------------
+    embed_scale: float = 1.0     # token embeddings times this
+    attn_multiplier: float = 0.0  # scores times this; 0: 1/sqrt(head_dim)
+    logits_scaling: float = 1.0  # logits divided by this
 
     # ----------------------------------------------------------------------
     @property
@@ -115,12 +125,20 @@ class ArchConfig:
         ffn_mats = 3 if self.act == "swiglu" else 2
         dense_ffn = ffn_mats * d * f
         moe_ffn = self.n_experts * ffn_mats * d * f + d * self.n_experts
-        mamba_layer = (d * 2 * self.d_inner            # in_proj
-                       + self.d_inner * self.d_conv    # conv
-                       + self.d_inner * (self.d_state * 2 + 1)  # x_proj-ish
-                       + self.d_inner                  # dt
-                       + self.d_inner * self.d_state   # A
-                       + self.d_inner * d)             # out_proj
+        if self.ssm_kind == "mamba2":
+            conv = self.d_inner + 2 * self.ssm_groups * self.d_state
+            mamba_layer = (d * (self.d_inner + conv + self.ssm_heads)  # in
+                           + (self.d_conv + 1) * conv        # conv + bias
+                           + 3 * self.ssm_heads              # dt_bias, A, D
+                           + self.d_inner                    # gated norm
+                           + self.d_inner * d)               # out_proj
+        else:
+            mamba_layer = (d * 2 * self.d_inner            # in_proj
+                           + self.d_inner * self.d_conv    # conv
+                           + self.d_inner * (self.d_state * 2 + 1)  # x_proj-ish
+                           + self.d_inner                  # dt
+                           + self.d_inner * self.d_state   # A
+                           + self.d_inner * d)             # out_proj
         rwkv_layer = 6 * d * d + 3 * d * 32            # r,k,v,g,o,ffn-ish lora
         total = counts["embed"]
         active = counts["embed"]
@@ -210,6 +228,10 @@ def smoke(cfg: ArchConfig) -> ArchConfig:
         changes["top_k"] = min(cfg.top_k, 2)
     if cfg.family == "hybrid":
         changes["n_layers"] = max(cfg.attn_every, 4)
+    if cfg.ssm_kind == "mamba2":
+        changes.update(ssm_head_dim=16,
+                       ssm_heads=cfg.expand * changes["d_model"] // 16,
+                       d_state=16, ssm_chunk=8)
     return replace(cfg, **changes)
 
 

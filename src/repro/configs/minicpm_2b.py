@@ -4,8 +4,9 @@
 The WSD (warmup-stable-decay) learning-rate schedule is the
 paper-specific training feature; it is implemented in
 ``repro.optim.schedule.wsd_schedule`` and selected by this config.
-MiniCPM ties input/output embeddings and scales residual branches by
-1.4/sqrt(n_layers) (mu-p inspired depth scaling).
+MiniCPM ties input/output embeddings, scales the token embeddings by
+sqrt(d_model) and the residual branches by 1.4/sqrt(n_layers) (mu-p
+inspired depth scaling).
 """
 
 import math
@@ -27,5 +28,6 @@ CONFIG = ArchConfig(
     norm="rmsnorm",
     rope_theta=10000.0,
     tie_embeddings=True,
+    embed_scale=math.sqrt(2304),
     residual_scale=1.4 / math.sqrt(40),
 )

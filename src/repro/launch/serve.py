@@ -106,6 +106,10 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     done = srv.serve(reqs)
     for r in sorted(done, key=lambda r: r.rid):
         print(f"req {r.rid}: prompt_len={len(r.prompt)} -> {r.output}")
+    flat = srv.stats.flat()
+    print(" ".join(f"{k}={flat['server.' + k]}" for k in (
+        "prefill_tokens", "cache_kv_bytes_per_slot",
+        "cache_state_bytes_per_slot")))
     print(srv.stats.dump_text())
 
 

@@ -17,6 +17,7 @@ architecture plugs into the same train/serve/dry-run drivers.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -140,6 +141,19 @@ class Model:
             "cache": self.cache_spec(B, S),
             "cur_len": sds((), i32),
         }
+
+
+def cache_bytes_by_kind(cache: Any) -> Dict[str, int]:
+    """Bytes of a cache tree (arrays or ``ShapeDtypeStruct``s) by leaf
+    kind: attention keys and values (leaves named ``k``, ``v``) and
+    recurrent state (everything else: conv, SSM, WKV, token-shift rows)."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(cache)
+    out = {"kv": 0, "state": 0}
+    for path, s in flat:
+        last = getattr(path[-1], "key", None) if path else None
+        out["kv" if last in ("k", "v") else "state"] += \
+            math.prod(s.shape) * s.dtype.itemsize
+    return out
 
 
 def build_model(cfg: ArchConfig) -> Model:

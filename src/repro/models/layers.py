@@ -154,12 +154,20 @@ def qkv_project(p: Dict, x, cfg, positions, sharder: Sharder):
     return q, k, v
 
 
+def scale_scores(scores, hd: int, multiplier: float = 0.0):
+    """Attention scores times ``multiplier``, or over sqrt(hd) where it
+    is 0 (``ArchConfig.attn_multiplier``)."""
+    if multiplier:
+        return scores * multiplier
+    return scores / math.sqrt(hd)
+
+
 def naive_causal_attention(q, k, v, q_pos, kv_pos, window: int = 0,
-                           cross: bool = False):
+                           cross: bool = False, multiplier: float = 0.0):
     """Reference attention.  q/k/v: (b, s, h, hd); positions (b, s)."""
     hd = q.shape[-1]
     scores = jnp.einsum("bqhk,bshk->bhqs", q, k).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
+    scores = scale_scores(scores, hd, multiplier)
     if not cross:
         mask = kv_pos[:, None, None, :] <= q_pos[:, None, :, None]
         if window:
@@ -172,7 +180,8 @@ def naive_causal_attention(q, k, v, q_pos, kv_pos, window: int = 0,
 
 
 def blockwise_attention(q, k, v, q_pos, kv_pos, window: int = 0,
-                        chunk: int = 1024, cross: bool = False):
+                        chunk: int = 1024, cross: bool = False,
+                        multiplier: float = 0.0):
     """Online-softmax attention, scanning KV chunks (flash-style).
 
     Pure jnp (runs everywhere); the oracle for the Pallas kernel.
@@ -183,7 +192,7 @@ def blockwise_attention(q, k, v, q_pos, kv_pos, window: int = 0,
     if skv % chunk:
         chunk = skv          # fall back to single chunk
     n_chunks = skv // chunk
-    scale = 1.0 / math.sqrt(hd)
+    scale = multiplier or 1.0 / math.sqrt(hd)
     qf = q * jnp.asarray(scale, q.dtype)
 
     kc = k.reshape(b, n_chunks, chunk, h, hd).transpose(1, 0, 2, 3, 4)
@@ -265,10 +274,12 @@ def attention_train(p: Dict, x, cfg, positions, sharder: Sharder,
     if k.shape[1] > chunk:
         out = blockwise_attention(q, k, v, q_pos, kv_pos,
                                   window=cfg.sliding_window, chunk=chunk,
-                                  cross=cross)
+                                  cross=cross,
+                                  multiplier=cfg.attn_multiplier)
     else:
         out = naive_causal_attention(q, k, v, q_pos, kv_pos,
-                                     window=cfg.sliding_window, cross=cross)
+                                     window=cfg.sliding_window, cross=cross,
+                                     multiplier=cfg.attn_multiplier)
     # sequence-parallel out-projection (see mamba.apply_mamba): reshard
     # (seq <- model, heads full) before contracting over the sharded
     # head axis, replacing a full-sequence f32 partial-sum + all-reduce
@@ -357,7 +368,7 @@ def attention_decode(p: Dict, x, cfg, cache: Dict, cur_len,
     g = cfg.n_heads // kvh
     qg = q.reshape(b, kvh, g, hd)              # (b, kvh, g, hd)
     scores = jnp.einsum("bkgd,bksd->bkgs", qg, ck).astype(jnp.float32)
-    scores = scores / math.sqrt(hd)
+    scores = scale_scores(scores, hd, cfg.attn_multiplier)
 
     # slot validity: slot index < number of tokens written (incl. new one)
     n_valid = jnp.minimum(cur_len + 1, S)
@@ -432,8 +443,8 @@ def init_embedding(key, cfg) -> Dict:
 
 def embed_tokens(p: Dict, tokens, cfg, positions=None):
     x = jnp.take(p["table"], tokens, axis=0)
-    if cfg.tie_embeddings:
-        x = x * math.sqrt(cfg.d_model)    # minicpm-style embedding scale
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     if cfg.pos_scheme == "learned" and positions is not None:
         pos = positions if positions.ndim == 2 else positions[..., 0]
         x = x + jnp.take(p["pos_table"], pos, axis=0)
@@ -445,6 +456,8 @@ def unembed(p: Dict, x, cfg, sharder: Sharder = IDENTITY_SHARDER):
         logits = jnp.einsum("bsd,vd->bsv", x, p["table"])
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, p["head"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return sharder.ac(logits, ("batch", None, "vocab"))
 
 

@@ -43,7 +43,7 @@ def init_mamba_block(key, cfg) -> Dict:
     }
 
 
-def _causal_conv(p: Dict, x, conv_state: Optional[jnp.ndarray] = None):
+def causal_conv(p: Dict, x, conv_state: Optional[jnp.ndarray] = None):
     """Depthwise causal conv via shifted adds.  x: (b, s, di).
 
     conv_state: (b, d_conv-1, di) trailing inputs from the previous
@@ -128,7 +128,7 @@ def apply_mamba(p: Dict, x, cfg, sharder: Sharder = IDENTITY_SHARDER,
     xz = jnp.einsum("bsd,de->bse", x, p["in_proj"])
     xz = sharder.ac(xz, ("batch", None, "mlp"))
     xin, z = jnp.split(xz, 2, axis=-1)
-    xc, new_conv = _causal_conv(p, xin, conv_state)
+    xc, new_conv = causal_conv(p, xin, conv_state)
     xc = jax.nn.silu(xc)
 
     if x.shape[1] == 1 and ssm_state is not None:

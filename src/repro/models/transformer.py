@@ -1,6 +1,7 @@
 """Decoder LM assembly for all families: dense / moe / vlm (standard
-blocks), ssm (RWKV6 blocks), hybrid (Jamba period-8 Mamba+attention+MoE
-pattern).
+blocks), ssm (RWKV6 blocks), hybrid (a period of state-space layers and
+one attention layer: Jamba's Mamba-1 with MoE, period 8; Granite-4.0-H's
+Mamba-2, period 10; ``ArchConfig.ssm_kind`` picks the mixer).
 
 Layers execute under ``jax.lax.scan`` with stacked parameters so the
 HLO size is O(1) in depth (deepseek-67b = 95 layers compiles as one
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.models import layers as ll
 from repro.models import mamba as mm
+from repro.models import mamba2 as m2
 from repro.models import moe as me
 from repro.models import rwkv as rw
 from repro.models.common import (IDENTITY_SHARDER, Sharder, param,
@@ -54,8 +56,12 @@ def init_layer(key, cfg, layer_idx: int) -> Dict:
             "norm2": ll.init_norm(ks[2], cfg, cfg.d_model),
             "ffn": blk["channel_mix"],
         }
-    mixer = (ll.init_attention(ks[0], cfg) if kind == "attn"
-             else mm.init_mamba_block(ks[0], cfg))
+    if kind == "attn":
+        mixer = ll.init_attention(ks[0], cfg)
+    elif cfg.ssm_kind == "mamba2":
+        mixer = m2.init_mamba2_block(ks[0], cfg)
+    else:
+        mixer = mm.init_mamba_block(ks[0], cfg)
     ffn = (me.init_moe(ks[3], cfg) if cfg.is_moe_layer(layer_idx)
            else ll.init_mlp(ks[3], cfg))
     return {
@@ -102,12 +108,14 @@ def layer_cache_shape(cfg, layer_idx: int, batch: int, seq_len: int,
         return {"k": jax.ShapeDtypeStruct(shp, dtype),
                 "v": jax.ShapeDtypeStruct(shp, dtype)}
     if kind == "mamba":
-        return {
-            "conv": jax.ShapeDtypeStruct(
-                (batch, cfg.d_conv - 1, cfg.d_inner), dtype),
-            "ssm": jax.ShapeDtypeStruct(
-                (batch, cfg.d_inner, cfg.d_state), jnp.float32),
-        }
+        if cfg.ssm_kind == "mamba2":
+            conv = (batch, cfg.d_conv - 1, m2.conv_dim(cfg))
+            ssm = (batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.d_state)
+        else:
+            conv = (batch, cfg.d_conv - 1, cfg.d_inner)
+            ssm = (batch, cfg.d_inner, cfg.d_state)
+        return {"conv": jax.ShapeDtypeStruct(conv, dtype),
+                "ssm": jax.ShapeDtypeStruct(ssm, jnp.float32)}
     h = cfg.n_rwkv_heads
     n = cfg.rwkv_head_size
     return {
@@ -175,10 +183,15 @@ def apply_layer(p: Dict, x, cfg, layer_idx: int, sharder: Sharder,
 
     if kind == "mamba":
         st = cache or {}
-        mix, new_conv, new_ssm = mm.apply_mamba(
-            p["mixer"], h, cfg, sharder,
-            conv_state=st.get("conv"), ssm_state=st.get("ssm"),
-            remat=(mode == "train"))
+        if cfg.ssm_kind == "mamba2":
+            mix, new_conv, new_ssm = m2.apply_mamba2(
+                p["mixer"], h, cfg, sharder,
+                conv_state=st.get("conv"), ssm_state=st.get("ssm"))
+        else:
+            mix, new_conv, new_ssm = mm.apply_mamba(
+                p["mixer"], h, cfg, sharder,
+                conv_state=st.get("conv"), ssm_state=st.get("ssm"),
+                remat=(mode == "train"))
         new_cache = None
         if mode != "train":
             new_cache = {"conv": new_conv, "ssm": new_ssm}

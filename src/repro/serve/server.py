@@ -17,7 +17,9 @@ which the device sat idle, which part of the loop the host was in:
   (``step_num`` = the server's decode-step count before the step);
 - ``serve.fill``: admitting queued requests into free slots, holding a
   ``serve.prefill`` span per request (``rid``, ``prompt_len``): the
-  prefill, the cache insert and the read-back of the first token;
+  prefill, the cache insert and the read-back of the first token; inside
+  it, ``serve.insert`` around the copy of the request's KV and recurrent
+  state into its slot;
 - ``serve.dispatch``: the step's inputs put on the device and the
   batched decode step called (it runs asynchronously);
 - ``serve.sync``: waiting for the step's tokens to reach the host;
@@ -26,8 +28,11 @@ which the device sat idle, which part of the loop the host was in:
 
 The stats ``sync_wait`` and ``loop_host`` split every iteration into the
 seconds spent in ``serve.sync`` and the rest; ``queue_wait`` is each
-request's time from ``serve`` being called to its slot insert.  They are
-kept whether or not a profiler runs.
+request's time from ``serve`` being called to its slot insert;
+``prefill_tokens`` counts the prompt tokens prefilled, and
+``cache_kv_bytes_per_slot`` / ``cache_state_bytes_per_slot`` give one
+slot's cache by leaf kind (``cache_bytes_by_kind``).  They are kept
+whether or not a profiler runs.
 """
 
 from __future__ import annotations
@@ -41,13 +46,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.simobject import Param, SimObject
-from repro.models.api import Model
+from repro.models.api import Model, cache_bytes_by_kind
 from repro.serve.policy import SlotScheduler
 from repro.serve.step import build_decode_step, build_prefill_step
 
 SPAN_STEP = "serve.step"
 SPAN_FILL = "serve.fill"
 SPAN_PREFILL = "serve.prefill"
+SPAN_INSERT = "serve.insert"
 SPAN_DISPATCH = "serve.dispatch"
 SPAN_SYNC = "serve.sync"
 SPAN_EMIT = "serve.emit"
@@ -89,6 +95,17 @@ class BatchServer(SimObject):
             "loop_host", "rest of a loop iteration", unit="s")
         self.s_queue_wait = self.stats.distribution(
             "queue_wait", "submit to slot insert, per request", unit="s")
+        self.s_prefill_tokens = self.stats.scalar(
+            "prefill_tokens", "prompt tokens prefilled", unit="tokens")
+        slot_bytes = cache_bytes_by_kind(jax.eval_shape(
+            lambda: model.init_cache(1, self.seq_capacity)))
+        self.stats.formula("cache_kv_bytes_per_slot",
+                           lambda: slot_bytes["kv"],
+                           "attention keys and values of one slot",
+                           unit="bytes")
+        self.stats.formula("cache_state_bytes_per_slot",
+                           lambda: slot_bytes["state"],
+                           "recurrent state of one slot", unit="bytes")
         self.s_throughput = self.stats.formula(
             "tokens_per_decode_step",
             lambda: self.s_tokens.value() / max(self.s_decode_steps.value(),
@@ -101,10 +118,11 @@ class BatchServer(SimObject):
         batch = {"tokens": jnp.asarray(req.prompt[None, :], jnp.int32),
                  **{k: jnp.asarray(v)[None] for k, v in req.extras.items()}}
         logits, rcache = self._prefill(self.params, batch)
-        cache = jax.tree.map(
-            lambda c, rc: jax.lax.dynamic_update_slice_in_dim(
-                c, rc.astype(c.dtype), slot, 1),
-            cache, rcache)
+        with jax.profiler.TraceAnnotation(SPAN_INSERT):
+            cache = jax.tree.map(
+                lambda c, rc: jax.lax.dynamic_update_slice_in_dim(
+                    c, rc.astype(c.dtype), slot, 1),
+                cache, rcache)
         return cache, logits[0, -1]
 
     def replay_logits(self, req: Request) -> np.ndarray:
@@ -168,6 +186,7 @@ class BatchServer(SimObject):
                     SPAN_PREFILL, rid=req.rid, prompt_len=len(req.prompt)):
                 req.insert_time = time.perf_counter()
                 cache, logits = self._prefill_into(cache, slot, req)
+                self.s_prefill_tokens.inc(len(req.prompt))
                 tok = int(jax.device_get(jnp.argmax(
                     logits.astype(jnp.float32))))
                 req.output.append(tok)

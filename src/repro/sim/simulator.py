@@ -234,6 +234,9 @@ class Simulator:
         self._marker_exits: deque = deque()
         self._scheduled: List[Tuple[int, int, ExitEventType]] = []
         self._sched_seq = 0
+        # the tick the latest checkpoint drained the run to: a drain can
+        # finish the workload, and exits scheduled up to there still fire
+        self._drained_tick = -1
         self._started = False
         self._result: Optional[ExecResult] = None
         self.checkpoint_dir = checkpoint_dir
@@ -391,6 +394,7 @@ class Simulator:
             ckpt[ser.WORKLOAD_KEY] = self._dyn.state_dict()
             ckpt[ser.WORKLOAD_KIND_KEY] = type(self._dyn).__name__
         self.last_checkpoint = ckpt
+        self._drained_tick = max(self._drained_tick, int(ckpt["tick"]))
         path = None
         if save and self.checkpoint_dir:
             path = os.path.join(self.checkpoint_dir,
@@ -425,6 +429,12 @@ class Simulator:
 
     def _all_done(self) -> bool:
         return self._ex.done() and (self._dyn is None or self._dyn.done())
+
+    def _exit_due(self) -> bool:
+        """The next scheduled exit lies at or before the tick a
+        checkpoint's drain ran the workload to."""
+        return bool(self._scheduled) and \
+            self._scheduled[0][0] <= self._drained_tick
 
     # -- the exit-event loop ----------------------------------------------
     def run(self, verbose: Optional[bool] = None) -> Iterator[ExitEvent]:
@@ -490,7 +500,7 @@ class Simulator:
                                 cause=e["cause"],
                                 payload=dict(e.get("payload", {})))
                 continue
-            if self._all_done():
+            if self._all_done() and not self._exit_due():
                 if self._result is None:
                     self._result = self._ex.result()
                 # makespan tick, not queue tick: a restored run's queues
@@ -517,7 +527,7 @@ class Simulator:
                 self._ex.advance(max_tick=tick, stop_check=stop)
                 if self._stop_check():
                     continue                 # scheduled exit stays queued
-                if self._all_done():
+                if self._all_done() and not self._exit_due():
                     # workload ended before the exit point: drop it
                     self._scheduled.pop(0)
                     continue

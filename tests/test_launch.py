@@ -36,7 +36,7 @@ def _served(arch: str):
 
 
 @pytest.mark.parametrize("arch", ["stablelm-1.6b", "whisper-small",
-                                  "rwkv6-7b"])
+                                  "rwkv6-7b", "granite-4.0-h-micro"])
 def test_cache_path_matches_forward(arch):
     """Prefill + decode through the cache give the full forward pass's
     logits, within bf16 noise (measured 0.8% at 4 layers)."""

@@ -24,8 +24,8 @@ from repro.serve import server as S
 from repro.train import TrainOptions, build_train_step, init_train_state
 from repro.train import trainer as T
 
-SERVE_SPANS = (S.SPAN_STEP, S.SPAN_FILL, S.SPAN_PREFILL, S.SPAN_DISPATCH,
-               S.SPAN_SYNC, S.SPAN_EMIT)
+SERVE_SPANS = (S.SPAN_STEP, S.SPAN_FILL, S.SPAN_PREFILL, S.SPAN_INSERT,
+               S.SPAN_DISPATCH, S.SPAN_SYNC, S.SPAN_EMIT)
 TRAIN_SPANS = (T.SPAN_STEP, T.SPAN_INPUT, T.SPAN_DISPATCH, T.SPAN_SYNC,
                T.SPAN_PREFETCH)
 TRAIN_STEPS = 3
@@ -159,6 +159,15 @@ def test_serve_prefill_spans_carry_the_request(served):
         assert len(parent(p, fills)) == 1
 
 
+def test_serve_insert_spans_nest_in_their_prefill(served):
+    spans = served["spans"]
+    prefills = [s for s in spans if s.name == S.SPAN_PREFILL]
+    inserts = [s for s in spans if s.name == S.SPAN_INSERT]
+    assert len(inserts) == len(prefills) == len(requests())
+    for p in prefills:
+        assert len([i for i in inserts if i.inside(p)]) == 1
+
+
 def test_serve_tokens_identical_with_profiler_on(served):
     plain = {r.rid: r.output for r in served["plain"]}
     traced = {r.rid: r.output for r in served["traced"]}
@@ -175,6 +184,19 @@ def test_serve_counters_count_steps_and_requests(served):
     flat = srv.stats.flat()
     for name in ("sync_wait", "loop_host", "queue_wait"):
         assert flat[f"server.{name}"]["min"] >= 0.0
+
+
+def test_serve_counts_prefill_tokens_and_cache_bytes(served):
+    srv = served["srv"]
+    flat = srv.stats.flat()
+    assert flat["server.prefill_tokens"] == 2 * sum(
+        len(r.prompt) for r in requests())
+    # stablelm: k and v of every layer per position, no recurrent state
+    cfg = srv.model.cfg
+    assert flat["server.cache_kv_bytes_per_slot"] == (
+        2 * cfg.n_layers * cfg.n_kv_heads * srv.seq_capacity * cfg.head_dim
+        * 2)
+    assert flat["server.cache_state_bytes_per_slot"] == 0
 
 
 def test_serve_request_stamps_are_ordered(served):

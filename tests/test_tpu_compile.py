@@ -11,6 +11,8 @@ library may be loaded by one process at a time, so describing it while
 a module is imported would break the test workers that import it.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -75,12 +77,9 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_stablelm_decode_step_fits_one_v5e(one_chip):
-    """The server's decode step at 4 slots x 1024 capacity, f32 params as
-    ``BatchServer`` holds them."""
-    model = build_model(get_config("stablelm-1.6b"))
-    slots, capacity = 4, 1024
-
+def _decode_step_bytes(one_chip, model, slots, capacity):
+    """What the compiler says the server's decode step holds, f32 params
+    as ``BatchServer`` holds them."""
     def on_chip(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=one_chip), tree)
@@ -92,6 +91,21 @@ def test_stablelm_decode_step_fits_one_v5e(one_chip):
     compiled = jax.jit(build_decode_step(model)).lower(
         on_chip(params), on_chip(batch)).compile()
     ma = compiled.memory_analysis()
-    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def test_stablelm_decode_step_fits_one_v5e(one_chip):
+    """The server's decode step at 4 slots x 1024 capacity."""
+    total = _decode_step_bytes(
+        one_chip, build_model(get_config("stablelm-1.6b")), 4, 1024)
+    assert total <= HBM_BYTES, total
+
+
+def test_granite_stage_decode_step_fits_one_v5e(one_chip):
+    """One pipeline stage of granite-4.0-h-micro (20 of 40 layers) at the
+    benchmark cell's 32 slots x 4096: KV of 2 attention layers and 18
+    Mamba-2 states per slot beside the f32 params."""
+    cfg = dataclasses.replace(get_config("granite-4.0-h-micro"), n_layers=20)
+    total = _decode_step_bytes(one_chip, build_model(cfg), 32, 4096)
     assert total <= HBM_BYTES, total
