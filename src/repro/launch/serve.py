@@ -86,6 +86,7 @@ def check_against_forward(srv: BatchServer, req: Request) -> Dict[str, float]:
     tokens = np.concatenate([req.prompt, np.asarray(req.output[:-1])])
     batch = {"tokens": tokens[None].astype(np.int32),
              **{k: np.asarray(v)[None] for k, v in req.extras.items()}}
+    # srv.params: the server's compute-dtype tree, cast once from the caller's
     logits, _ = jax.jit(model.train_logits)(srv.params, batch)
     n, vocab = len(req.output), model.cfg.vocab_size
     ref = np.asarray(logits[0, -n:, :vocab].astype(np.float32))

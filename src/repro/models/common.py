@@ -20,6 +20,10 @@ import jax.numpy as jnp
 
 Leaf = Dict[str, Any]
 
+# The precision every forward pass computes in (matmuls, activations,
+# the serving cache); parameters are kept in float32 and cast to it.
+COMPUTE_DTYPE = jnp.bfloat16
+
 
 def param(key, shape: Tuple[int, ...], axes: Tuple[Optional[str], ...],
           dtype=jnp.float32, scale: Optional[float] = None,

@@ -15,8 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as ll
-from repro.models.common import (IDENTITY_SHARDER, Sharder, cast, split_key,
-                                 stack_inits)
+from repro.models.common import (COMPUTE_DTYPE, IDENTITY_SHARDER, Sharder,
+                                 cast, split_key, stack_inits)
 from repro.models.transformer import kv_capacity
 
 
@@ -208,7 +208,7 @@ def dec_forward(params: Dict, x, enc_out, cfg, sharder: Sharder, positions,
 def encdec_apply(params: Dict, batch: Dict, cfg,
                  sharder: Sharder = IDENTITY_SHARDER, mode: str = "train",
                  cache: Any = None, cur_len=None, chunk: int = 2048,
-                 seq_capacity: int = 0, compute_dtype=jnp.bfloat16
+                 seq_capacity: int = 0, compute_dtype=COMPUTE_DTYPE
                  ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
     """Returns (logits, cache, aux).  batch: tokens + enc_embeds (stub)."""
     params = cast(params, compute_dtype)

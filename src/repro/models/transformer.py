@@ -28,8 +28,8 @@ from repro.models import mamba as mm
 from repro.models import mamba2 as m2
 from repro.models import moe as me
 from repro.models import rwkv as rw
-from repro.models.common import (IDENTITY_SHARDER, Sharder, param,
-                                 split_key, stack_inits, unzip)
+from repro.models.common import (COMPUTE_DTYPE, IDENTITY_SHARDER, Sharder,
+                                 cast, param, split_key, stack_inits, unzip)
 
 
 # ---------------------------------------------------------------------------
@@ -347,14 +347,13 @@ def make_positions(cfg, b: int, s: int, n_vis: int = 0, offset: int = 0):
 def lm_apply(params: Dict, batch: Dict, cfg, sharder: Sharder = IDENTITY_SHARDER,
              mode: str = "train", cache: Any = None, cur_len=None,
              chunk: int = 2048, seq_capacity: int = 0,
-             compute_dtype=jnp.bfloat16) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
+             compute_dtype=COMPUTE_DTYPE) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
     """Unified LM entry.  Returns (logits, new_cache, aux_loss).
 
     train  : logits (b, s, Vp)
     prefill: logits (b, 1, Vp) at the last position, + cache
     decode : logits (b, 1, Vp), + updated cache
     """
-    from repro.models.common import cast
     params = cast(params, compute_dtype)
     tokens = batch["tokens"]
     b = tokens.shape[0]

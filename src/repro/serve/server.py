@@ -31,8 +31,9 @@ seconds spent in ``serve.sync`` and the rest; ``queue_wait`` is each
 request's time from ``serve`` being called to its slot insert;
 ``prefill_tokens`` counts the prompt tokens prefilled, and
 ``cache_kv_bytes_per_slot`` / ``cache_state_bytes_per_slot`` give one
-slot's cache by leaf kind (``cache_bytes_by_kind``).  They are kept
-whether or not a profiler runs.
+slot's cache by leaf kind (``cache_bytes_by_kind``), and ``param_bytes``
+the parameters the steps read.  They are kept whether or not a profiler
+runs.
 """
 
 from __future__ import annotations
@@ -47,6 +48,7 @@ import numpy as np
 
 from repro.core.simobject import Param, SimObject
 from repro.models.api import Model, cache_bytes_by_kind
+from repro.models.common import COMPUTE_DTYPE, cast
 from repro.serve.policy import SlotScheduler
 from repro.serve.step import build_decode_step, build_prefill_step
 
@@ -74,6 +76,21 @@ class Request:
 
 
 class BatchServer(SimObject):
+    """``slots`` decode slots of ``seq_capacity`` positions, filled by
+    B=1 prefills and advanced together by one batched decode step.
+
+    The server holds compute-dtype params built once from the caller's
+    tree: ``self.params`` is ``params`` with every float leaf cast to
+    ``COMPUTE_DTYPE`` when the server is built, integer leaves as they
+    are.  The jitted ``prefill_step`` and ``decode_step`` read that tree
+    on every call, so no step converts a weight.  The weights' values
+    are those the models would cast to themselves; the caller's tree is
+    left unmodified.  (The steps then compile to other programs, so on a
+    TPU the bf16 logits may differ from those of the caller's tree in
+    their last bits.)  Stats (see the module docstring) include
+    ``param_bytes``, the bytes of that tree.
+    """
+
     slots = Param(int, 4, "decode batch size")
     seq_capacity = Param(int, 128, "KV/state capacity per slot")
 
@@ -81,7 +98,7 @@ class BatchServer(SimObject):
                  **kw):
         super().__init__(name, **kw)
         self.model = model
-        self.params = params
+        self.params = cast(params, COMPUTE_DTYPE)
         self._prefill = jax.jit(build_prefill_step(
             model, seq_capacity=self.seq_capacity))
         self._decode = jax.jit(build_decode_step(model))
@@ -106,6 +123,9 @@ class BatchServer(SimObject):
         self.stats.formula("cache_state_bytes_per_slot",
                            lambda: slot_bytes["state"],
                            "recurrent state of one slot", unit="bytes")
+        param_bytes = sum(x.nbytes for x in jax.tree.leaves(self.params))
+        self.stats.formula("param_bytes", lambda: param_bytes,
+                           "parameters the steps read", unit="bytes")
         self.s_throughput = self.stats.formula(
             "tokens_per_decode_step",
             lambda: self.s_tokens.value() / max(self.s_decode_steps.value(),
