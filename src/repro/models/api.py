@@ -27,28 +27,31 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.models import encdec as ed
 from repro.models import transformer as tf
-from repro.models.common import IDENTITY_SHARDER, Sharder, unzip
+from repro.models.common import (COMPUTE_DTYPE, IDENTITY_SHARDER, Sharder,
+                                 cast, unzip)
 
 
 @dataclass
 class Model:
+    """``cfg`` and its family's functions, chosen by ``build_model``:
+    ``init_fn(key, cfg)`` (marker tree), ``apply_fn(params, batch, cfg,
+    sharder, mode=..., ...)`` -> (logits, cache, aux) in the dtype of
+    ``params``, and ``cache_spec_fn(cfg, batch, seq_len, dtype)``."""
     cfg: ArchConfig
+    init_fn: Callable
+    apply_fn: Callable
+    cache_spec_fn: Callable
 
     # ------------------------------------------------------------------
-    def _init_fn(self) -> Callable:
-        if self.cfg.family == "audio":
-            return ed.init_encdec
-        return tf.init_lm
-
     def init(self, key) -> Any:
-        vals, _ = unzip(self._init_fn()(key, self.cfg))
+        vals, _ = unzip(self.init_fn(key, self.cfg))
         return vals
 
     def param_specs(self) -> Tuple[Any, Any]:
         box: Dict[str, Any] = {}
 
         def f(key):
-            t = self._init_fn()(key, self.cfg)
+            t = self.init_fn(key, self.cfg)
             vals, axes = unzip(t)
             box["axes"] = axes
             return vals
@@ -57,45 +60,32 @@ class Model:
         return shapes, box["axes"]
 
     # ------------------------------------------------------------------
+    # The forward passes compute in COMPUTE_DTYPE: float leaves of
+    # ``params`` are cast to it first.
     def train_logits(self, params, batch, sharder: Sharder = IDENTITY_SHARDER,
                      chunk: int = 2048) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        if self.cfg.family == "audio":
-            logits, _, aux = ed.encdec_apply(params, batch, self.cfg, sharder,
-                                             mode="train", chunk=chunk)
-        else:
-            logits, _, aux = tf.lm_apply(params, batch, self.cfg, sharder,
-                                         mode="train", chunk=chunk)
+        logits, _, aux = self.apply_fn(cast(params, COMPUTE_DTYPE), batch,
+                                       self.cfg, sharder, mode="train",
+                                       chunk=chunk)
         return logits, aux
 
     def prefill(self, params, batch, sharder: Sharder = IDENTITY_SHARDER,
                 chunk: int = 2048, seq_capacity: int = 0):
-        if self.cfg.family == "audio":
-            logits, cache, _ = ed.encdec_apply(
-                params, batch, self.cfg, sharder, mode="prefill", chunk=chunk,
-                seq_capacity=seq_capacity)
-        else:
-            logits, cache, _ = tf.lm_apply(
-                params, batch, self.cfg, sharder, mode="prefill", chunk=chunk,
-                seq_capacity=seq_capacity)
+        logits, cache, _ = self.apply_fn(
+            cast(params, COMPUTE_DTYPE), batch, self.cfg, sharder,
+            mode="prefill", chunk=chunk, seq_capacity=seq_capacity)
         return logits, cache
 
     def decode(self, params, batch, cache, cur_len,
                sharder: Sharder = IDENTITY_SHARDER):
-        if self.cfg.family == "audio":
-            logits, cache, _ = ed.encdec_apply(
-                params, batch, self.cfg, sharder, mode="decode", cache=cache,
-                cur_len=cur_len)
-        else:
-            logits, cache, _ = tf.lm_apply(
-                params, batch, self.cfg, sharder, mode="decode", cache=cache,
-                cur_len=cur_len)
+        logits, cache, _ = self.apply_fn(
+            cast(params, COMPUTE_DTYPE), batch, self.cfg, sharder,
+            mode="decode", cache=cache, cur_len=cur_len)
         return logits, cache
 
     # ------------------------------------------------------------------
     def cache_spec(self, batch: int, seq_len: int, dtype=jnp.bfloat16):
-        if self.cfg.family == "audio":
-            return ed.encdec_cache_spec(self.cfg, batch, seq_len, dtype)
-        return tf.cache_spec(self.cfg, batch, seq_len, dtype)
+        return self.cache_spec_fn(self.cfg, batch, seq_len, dtype)
 
     def init_cache(self, batch: int, seq_len: int, dtype=jnp.bfloat16):
         return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
@@ -157,4 +147,7 @@ def cache_bytes_by_kind(cache: Any) -> Dict[str, int]:
 
 
 def build_model(cfg: ArchConfig) -> Model:
-    return Model(cfg)
+    if cfg.family == "audio":
+        return Model(cfg, ed.init_encdec, ed.encdec_apply,
+                     ed.encdec_cache_spec)
+    return Model(cfg, tf.init_lm, tf.lm_apply, tf.cache_spec)

@@ -15,9 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as ll
-from repro.models.common import (COMPUTE_DTYPE, IDENTITY_SHARDER, Sharder,
-                                 cast, split_key, stack_inits)
-from repro.models.transformer import kv_capacity
+from repro.models.common import IDENTITY_SHARDER, Sharder, split_key, stack_inits
+from repro.models.transformer import scan_layers
 
 
 # ---------------------------------------------------------------------------
@@ -128,90 +127,48 @@ def dec_forward(params: Dict, x, enc_out, cfg, sharder: Sharder, positions,
     {"self": {k,v}, "cross": {k,v (b, kvh, enc_seq, hd)}}."""
     seq_capacity = seq_capacity or x.shape[1]
 
-    def body(carry, xs):
-        x, = carry
-        lp, lc = xs
+    def layer(x, lp, lc):
+        h = ll.apply_norm(lp["norm1"], x, cfg)
+        a, new_self = ll.attention(
+            lp["self_attn"], h, cfg, mode, sharder, positions=positions,
+            cache=None if lc is None else lc["self"], cur_len=cur_len,
+            chunk=chunk, seq_capacity=seq_capacity)
+        x = x + a
+        hx = ll.apply_norm(lp["norm_x"], x, cfg)
+        if mode == "decode":
+            c = _decode_cross(lp, hx, cfg, lc["cross"], sharder)
+            new_cross = lc["cross"]
+        else:
+            ck, cv = _cross_kv(lp, enc_out, cfg)
+            enc_pos = jnp.broadcast_to(
+                jnp.arange(enc_out.shape[1]), enc_out.shape[:2])
+            c = ll.attention_train(
+                lp["cross_attn"], hx, cfg, positions, sharder,
+                kv=(ll._repeat_kv(ck, cfg.n_heads),
+                    ll._repeat_kv(cv, cfg.n_heads), enc_pos),
+                chunk=chunk)
+            new_cross = {"k": ck.transpose(0, 2, 1, 3),
+                         "v": cv.transpose(0, 2, 1, 3)}
+        x = x + c
+        h2 = ll.apply_norm(lp["norm2"], x, cfg)
+        x = x + ll.apply_mlp(lp["ffn"], h2, cfg, sharder)
+        x = sharder.ac(x, ("batch", "seq", None))
+        new_cache = None
+        if mode != "train":
+            new_cache = {"self": new_self, "cross": new_cross}
+        return x, new_cache, None
 
-        def fn(x, lp, lc):
-            h = ll.apply_norm(lp["norm1"], x, cfg)
-            new_cache = None
-            if mode == "decode":
-                a, new_self = ll.attention_decode(
-                    lp["self_attn"], h, cfg, lc["self"], cur_len, sharder)
-            elif mode == "prefill":
-                a, (kr, vr) = ll.attention_train(
-                    lp["self_attn"], h, cfg, positions, sharder, chunk=chunk,
-                    return_kv=True)
-                new_self = ll.kv_to_cache(kr, vr,
-                                          kv_capacity(cfg, seq_capacity),
-                                          sharder)
-            else:
-                a = ll.attention_train(lp["self_attn"], h, cfg, positions,
-                                       sharder, chunk=chunk)
-                new_self = None
-            x = x + a
-            hx = ll.apply_norm(lp["norm_x"], x, cfg)
-            if mode == "decode":
-                c = _decode_cross(lp, hx, cfg, lc["cross"], sharder)
-                new_cross = lc["cross"]
-            else:
-                ck, cv = _cross_kv(lp, enc_out, cfg)
-                enc_pos = jnp.broadcast_to(
-                    jnp.arange(enc_out.shape[1]), enc_out.shape[:2])
-                c = ll.attention_train(
-                    lp["cross_attn"], hx, cfg, positions, sharder,
-                    kv=(ll._repeat_kv(ck, cfg.n_heads),
-                        ll._repeat_kv(cv, cfg.n_heads), enc_pos),
-                    chunk=chunk)
-                new_cross = {"k": ck.transpose(0, 2, 1, 3),
-                             "v": cv.transpose(0, 2, 1, 3)}
-            x = x + c
-            h2 = ll.apply_norm(lp["norm2"], x, cfg)
-            x = x + ll.apply_mlp(lp["ffn"], h2, cfg, sharder)
-            x = sharder.ac(x, ("batch", "seq", None))
-            if mode == "prefill":
-                new_cache = {"self": new_self, "cross": new_cross}
-            elif mode == "decode":
-                new_cache = {"self": new_self, "cross": new_cross}
-            return x, new_cache
-
-        if mode == "train":
-            x, nc = jax.checkpoint(fn)(x, lp, lc)
-            return (x,), 0.0
-        x, nc = fn(x, lp, lc)
-        return (x,), nc
-
-    if mode == "decode":
-        # carry the cache: single aliased buffer (see transformer.py)
-        def dbody(carry, lp):
-            x, cache_all, li = carry
-            lc = jax.tree.map(
-                lambda c: jax.lax.dynamic_index_in_dim(c, li, 0,
-                                                       keepdims=False),
-                cache_all)
-            (x,), nc = body((x,), (lp, lc))
-            cache_all = jax.tree.map(
-                lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                    c, n.astype(c.dtype), li, 0), cache_all, nc)
-            return (x, cache_all, li + 1), None
-
-        (x, cache, _), _ = jax.lax.scan(
-            dbody, (x, cache, 0), params["dec_layers"],
-            length=cfg.n_layers)
-        return x, cache
-
-    xs = (params["dec_layers"], cache)
-    (x,), caches = jax.lax.scan(body, (x,), xs, length=cfg.n_layers)
-    return x, (caches if mode != "train" else None)
+    x, cache, _ = scan_layers(layer, params["dec_layers"], x, mode, cache)
+    return x, cache
 
 
 def encdec_apply(params: Dict, batch: Dict, cfg,
                  sharder: Sharder = IDENTITY_SHARDER, mode: str = "train",
                  cache: Any = None, cur_len=None, chunk: int = 2048,
-                 seq_capacity: int = 0, compute_dtype=COMPUTE_DTYPE
+                 seq_capacity: int = 0
                  ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Returns (logits, cache, aux).  batch: tokens + enc_embeds (stub)."""
-    params = cast(params, compute_dtype)
+    """Returns (logits, cache, aux), computing in the dtype of ``params``.
+    batch: tokens + enc_embeds (stub)."""
     tokens = batch["tokens"]
     b = tokens.shape[0]
     if mode == "decode":
@@ -220,7 +177,8 @@ def encdec_apply(params: Dict, batch: Dict, cfg,
         embed_pos = jnp.broadcast_to(
             jnp.reshape(jnp.asarray(cur_len, jnp.int32), (-1, 1)), (b, 1))
     else:
-        enc_out = encode(params, batch["enc_embeds"].astype(compute_dtype),
+        enc_out = encode(params,
+                         batch["enc_embeds"].astype(params["enc_pos"].dtype),
                          cfg, sharder, chunk=chunk)
         s = tokens.shape[1]
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -238,7 +196,7 @@ def encdec_apply(params: Dict, batch: Dict, cfg,
 
 
 def encdec_cache_spec(cfg, batch: int, seq_len: int, dtype=jnp.bfloat16):
-    S = kv_capacity(cfg, seq_len)
+    S = ll.kv_capacity(cfg, seq_len)
     self_shp = (cfg.n_layers, batch, cfg.n_kv_heads, S, cfg.head_dim)
     cross_shp = (cfg.n_layers, batch, cfg.n_kv_heads, cfg.enc_seq,
                  cfg.head_dim)

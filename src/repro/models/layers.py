@@ -317,7 +317,7 @@ def kv_to_cache(k, v, capacity: int, sharder: Sharder):
 
 
 def attention_decode(p: Dict, x, cfg, cache: Dict, cur_len,
-                     sharder: Sharder, update_cache: bool = True):
+                     sharder: Sharder):
     """Single-token decode with a (possibly ring-buffered) KV cache.
 
     x: (b, 1, d).  cache: {"k": (b, kvh, S, hd), "v": ...}.  cur_len:
@@ -346,21 +346,16 @@ def attention_decode(p: Dict, x, cfg, cache: Dict, cur_len,
     k_new = apply_rope(cfg, k_new, pos_now if cfg.pos_scheme != "mrope"
                        else jnp.broadcast_to(pos_now[..., None], (b, 1, 3)))
     slot = jnp.mod(cur_len, S)                 # ring buffer (sliding window)
-    if update_cache:
-        knc = k_new.transpose(0, 2, 1, 3)      # (b, kvh, 1, hd)
-        vnc = v_new.transpose(0, 2, 1, 3)
-        if per_slot:
-            hit = (jnp.arange(S)[None, :] == slot[:, None])   # (b, S)
-            hit = hit[:, None, :, None]
-            ck = jnp.where(hit, knc, cache["k"])
-            cv = jnp.where(hit, vnc, cache["v"])
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], knc, slot, 2)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], vnc, slot, 2)
+    knc = k_new.transpose(0, 2, 1, 3)          # (b, kvh, 1, hd)
+    vnc = v_new.transpose(0, 2, 1, 3)
+    if per_slot:
+        hit = (jnp.arange(S)[None, :] == slot[:, None])   # (b, S)
+        hit = hit[:, None, :, None]
+        ck = jnp.where(hit, knc, cache["k"])
+        cv = jnp.where(hit, vnc, cache["v"])
     else:
-        ck, cv = cache["k"], cache["v"]
+        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], knc, slot, 2)
+        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], vnc, slot, 2)
     ck = sharder.ac(ck, ("batch", "kv_heads_c", "kv_seq", None))
     cv = sharder.ac(cv, ("batch", "kv_heads_c", "kv_seq", None))
 
@@ -385,6 +380,34 @@ def attention_decode(p: Dict, x, cfg, cache: Dict, cur_len,
     out = out.reshape(b, 1, cfg.n_heads, hd)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, {"k": ck, "v": cv}
+
+
+def kv_capacity(cfg, seq_len: int) -> int:
+    """Slots of a KV cache for ``seq_len`` positions: a sliding window
+    keeps only its last ``sliding_window``."""
+    if cfg.sliding_window:
+        return min(seq_len, cfg.sliding_window)
+    return seq_len
+
+
+def attention(p: Dict, x, cfg, mode: str, sharder: Sharder, *, positions,
+              cache: Optional[Dict], cur_len, chunk: int, seq_capacity: int):
+    """Causal self-attention in one of the three modes.  Returns
+    (out, new_cache):
+
+      train   -> over all positions; no cache
+      prefill -> over all positions; the prompt's K/V as a cache of
+                 ``kv_capacity(cfg, seq_capacity)`` slots
+      decode  -> one token against ``cache`` at ``cur_len``; the cache
+                 with that token written
+    """
+    if mode == "decode":
+        return attention_decode(p, x, cfg, cache, cur_len, sharder)
+    if mode == "prefill":
+        y, (k, v) = attention_train(p, x, cfg, positions, sharder,
+                                    chunk=chunk, return_kv=True)
+        return y, kv_to_cache(k, v, kv_capacity(cfg, seq_capacity), sharder)
+    return attention_train(p, x, cfg, positions, sharder, chunk=chunk), None
 
 
 # ---------------------------------------------------------------------------

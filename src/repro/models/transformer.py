@@ -3,10 +3,11 @@ blocks), ssm (RWKV6 blocks), hybrid (a period of state-space layers and
 one attention layer: Jamba's Mamba-1 with MoE, period 8; Granite-4.0-H's
 Mamba-2, period 10; ``ArchConfig.ssm_kind`` picks the mixer).
 
-Layers execute under ``jax.lax.scan`` with stacked parameters so the
-HLO size is O(1) in depth (deepseek-67b = 95 layers compiles as one
-while loop).  Hybrid archs scan over *periods* (Jamba: 4 periods of 8
-sublayers each, attention at position 4, MoE on odd positions).
+Layers execute under ``scan_layers`` (one ``jax.lax.scan``, shared with
+the encoder-decoder's decoder) with stacked parameters so the HLO size
+is O(1) in depth (deepseek-67b = 95 layers compiles as one while loop).
+Hybrid archs scan over *periods* (Jamba: 4 periods of 8 sublayers each,
+attention at position 4, MoE on odd positions).
 
 Three modes share the block code:
   train   -> logits over all positions (activation-rematerialized)
@@ -17,7 +18,6 @@ Three modes share the block code:
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -28,8 +28,8 @@ from repro.models import mamba as mm
 from repro.models import mamba2 as m2
 from repro.models import moe as me
 from repro.models import rwkv as rw
-from repro.models.common import (COMPUTE_DTYPE, IDENTITY_SHARDER, Sharder,
-                                 cast, param, split_key, stack_inits, unzip)
+from repro.models.common import (IDENTITY_SHARDER, Sharder, split_key,
+                                 stack_inits)
 
 
 # ---------------------------------------------------------------------------
@@ -37,10 +37,11 @@ from repro.models.common import (COMPUTE_DTYPE, IDENTITY_SHARDER, Sharder,
 # ---------------------------------------------------------------------------
 
 def layer_kind(cfg, layer_idx: int) -> str:
+    """The layer's mixer: ``attn``, ``mamba1``, ``mamba2`` or ``rwkv``."""
     if cfg.family == "ssm":
         return "rwkv"
     if cfg.family == "hybrid" and not cfg.is_attn_layer(layer_idx):
-        return "mamba"
+        return cfg.ssm_kind
     return "attn"
 
 
@@ -56,17 +57,13 @@ def init_layer(key, cfg, layer_idx: int) -> Dict:
             "norm2": ll.init_norm(ks[2], cfg, cfg.d_model),
             "ffn": blk["channel_mix"],
         }
-    if kind == "attn":
-        mixer = ll.init_attention(ks[0], cfg)
-    elif cfg.ssm_kind == "mamba2":
-        mixer = m2.init_mamba2_block(ks[0], cfg)
-    else:
-        mixer = mm.init_mamba_block(ks[0], cfg)
+    init_mixer = {"attn": ll.init_attention, "mamba1": mm.init_mamba_block,
+                  "mamba2": m2.init_mamba2_block}[kind]
     ffn = (me.init_moe(ks[3], cfg) if cfg.is_moe_layer(layer_idx)
            else ll.init_mlp(ks[3], cfg))
     return {
         "norm1": ll.init_norm(ks[1], cfg, cfg.d_model),
-        "mixer": mixer,
+        "mixer": init_mixer(ks[0], cfg),
         "norm2": ll.init_norm(ks[2], cfg, cfg.d_model),
         "ffn": ffn,
     }
@@ -93,36 +90,30 @@ def init_decoder_layers(key, cfg) -> Any:
 # Cache construction (zeros / specs)
 # ---------------------------------------------------------------------------
 
-def kv_capacity(cfg, seq_len: int) -> int:
-    if cfg.sliding_window:
-        return min(seq_len, cfg.sliding_window)
-    return seq_len
-
-
 def layer_cache_shape(cfg, layer_idx: int, batch: int, seq_len: int,
                       dtype=jnp.bfloat16) -> Dict:
     kind = layer_kind(cfg, layer_idx)
     if kind == "attn":
-        S = kv_capacity(cfg, seq_len)
+        S = ll.kv_capacity(cfg, seq_len)
         shp = (batch, cfg.n_kv_heads, S, cfg.head_dim)
         return {"k": jax.ShapeDtypeStruct(shp, dtype),
                 "v": jax.ShapeDtypeStruct(shp, dtype)}
-    if kind == "mamba":
-        if cfg.ssm_kind == "mamba2":
-            conv = (batch, cfg.d_conv - 1, m2.conv_dim(cfg))
-            ssm = (batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.d_state)
-        else:
-            conv = (batch, cfg.d_conv - 1, cfg.d_inner)
-            ssm = (batch, cfg.d_inner, cfg.d_state)
-        return {"conv": jax.ShapeDtypeStruct(conv, dtype),
-                "ssm": jax.ShapeDtypeStruct(ssm, jnp.float32)}
-    h = cfg.n_rwkv_heads
-    n = cfg.rwkv_head_size
-    return {
-        "shift_tm": jax.ShapeDtypeStruct((batch, 1, cfg.d_model), dtype),
-        "shift_cm": jax.ShapeDtypeStruct((batch, 1, cfg.d_model), dtype),
-        "wkv": jax.ShapeDtypeStruct((batch, h, n, n), jnp.float32),
-    }
+    if kind == "rwkv":
+        h = cfg.n_rwkv_heads
+        n = cfg.rwkv_head_size
+        return {
+            "shift_tm": jax.ShapeDtypeStruct((batch, 1, cfg.d_model), dtype),
+            "shift_cm": jax.ShapeDtypeStruct((batch, 1, cfg.d_model), dtype),
+            "wkv": jax.ShapeDtypeStruct((batch, h, n, n), jnp.float32),
+        }
+    if kind == "mamba2":
+        conv = (batch, cfg.d_conv - 1, m2.conv_dim(cfg))
+        ssm = (batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.d_state)
+    else:
+        conv = (batch, cfg.d_conv - 1, cfg.d_inner)
+        ssm = (batch, cfg.d_inner, cfg.d_state)
+    return {"conv": jax.ShapeDtypeStruct(conv, dtype),
+            "ssm": jax.ShapeDtypeStruct(ssm, jnp.float32)}
 
 
 def _stack_specs(specs):
@@ -162,9 +153,9 @@ def apply_layer(p: Dict, x, cfg, layer_idx: int, sharder: Sharder,
     rs = cfg.residual_scale
     aux = jnp.zeros((), jnp.float32)
     h = ll.apply_norm(p["norm1"], x, cfg)
+    st = cache or {}
 
     if kind == "rwkv":
-        st = cache or {}
         mix, new_shift_tm, new_wkv = rw.apply_time_mix(
             p["mixer"], h, cfg, sharder,
             shift_state=st.get("shift_tm"), wkv_state=st.get("wkv"))
@@ -181,9 +172,13 @@ def apply_layer(p: Dict, x, cfg, layer_idx: int, sharder: Sharder,
                          "wkv": new_wkv}
         return x, new_cache, aux
 
-    if kind == "mamba":
-        st = cache or {}
-        if cfg.ssm_kind == "mamba2":
+    if kind == "attn":
+        mix, new_cache = ll.attention(
+            p["mixer"], h, cfg, mode, sharder, positions=positions,
+            cache=cache, cur_len=cur_len, chunk=chunk,
+            seq_capacity=seq_capacity)
+    else:
+        if kind == "mamba2":
             mix, new_conv, new_ssm = m2.apply_mamba2(
                 p["mixer"], h, cfg, sharder,
                 conv_state=st.get("conv"), ssm_state=st.get("ssm"))
@@ -195,20 +190,6 @@ def apply_layer(p: Dict, x, cfg, layer_idx: int, sharder: Sharder,
         new_cache = None
         if mode != "train":
             new_cache = {"conv": new_conv, "ssm": new_ssm}
-    else:  # attention
-        if mode == "decode":
-            mix, new_cache = ll.attention_decode(
-                p["mixer"], h, cfg, cache, cur_len, sharder)
-        elif mode == "prefill":
-            mix, (k_raw, v_raw) = ll.attention_train(
-                p["mixer"], h, cfg, positions, sharder, chunk=chunk,
-                return_kv=True)
-            new_cache = ll.kv_to_cache(
-                k_raw, v_raw, kv_capacity(cfg, seq_capacity), sharder)
-        else:
-            mix = ll.attention_train(p["mixer"], h, cfg, positions, sharder,
-                                     chunk=chunk)
-            new_cache = None
 
     x = x + rs * mix
     x = sharder.ac(x, ("batch", "seq", None))
@@ -223,8 +204,74 @@ def apply_layer(p: Dict, x, cfg, layer_idx: int, sharder: Sharder,
 
 
 # ---------------------------------------------------------------------------
-# Decoder stack (scan over layers / periods)
+# Layer stack (one scan over layers / periods, for every family)
 # ---------------------------------------------------------------------------
+
+def scan_layers(layers, params: Any, x, mode: str, cache: Any = None,
+                aux=None) -> Tuple[jnp.ndarray, Any, Any]:
+    """Run a stack of layers over ``x`` under one ``lax.scan``.  Returns
+    (x, new_cache, aux).
+
+    ``layers`` is one layer function ``fn(x, layer_params, layer_cache)
+    -> (x, new_layer_cache, layer_aux)``, with ``params`` and ``cache``
+    its stacked trees (leading axis: the layers).  A hybrid period is a
+    tuple of such functions, one per position, each with its own stacked
+    tree in the tuples ``params`` and ``cache`` (leading axis: the
+    periods); each scan step runs the whole period.  ``aux``, where not
+    None, is a float32 scalar the layers' ``layer_aux`` are added to.
+
+      train   -> each layer under ``jax.checkpoint``; no cache (None)
+      prefill -> each layer's cache emitted as the scan's ``ys``
+      decode  -> ``cache`` carried whole through the scan; each step
+                 reads its layer's slice with ``dynamic_index_in_dim``
+                 and writes it back with ``dynamic_update_index_in_dim``
+
+    Decode carries the cache because XLA aliases while-loop carries, so
+    the multi-GB cache exists ONCE.  Passing it as scan xs/ys instead
+    double-buffers it (measured +12.8 GB/device at deepseek decode_32k
+    scale).
+    """
+    single = callable(layers)
+    if single:
+        layers, params, cache = (layers,), (params,), (cache,)
+
+    def apply(fn, x, aux, lp, lc):
+        if mode == "train":
+            fn = jax.checkpoint(fn)
+        x, nc, a = fn(x, lp, lc)
+        return x, nc, (aux if aux is None else aux + a)
+
+    if mode == "decode":
+        def body(carry, lp):
+            x, aux, cache, li = carry
+            new = []
+            for fn, p, c in zip(layers, lp, cache):
+                lc = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
+                    t, li, 0, keepdims=False), c)
+                x, nc, aux = apply(fn, x, aux, p, lc)
+                new.append(jax.tree.map(
+                    lambda t, n: jax.lax.dynamic_update_index_in_dim(
+                        t, n.astype(t.dtype), li, 0), c, nc))
+            return (x, aux, tuple(new), li + 1), None
+
+        (x, aux, cache, _), _ = jax.lax.scan(body, (x, aux, cache, 0),
+                                             params)
+    else:
+        def body(carry, lp):
+            x, aux = carry
+            new = []
+            for fn, p in zip(layers, lp):
+                x, nc, aux = apply(fn, x, aux, p, None)
+                new.append(nc)
+            return (x, aux), tuple(new)
+
+        (x, aux), cache = jax.lax.scan(body, (x, aux), params)
+        if mode == "train":
+            cache = None
+    if single and cache is not None:
+        cache = cache[0]
+    return x, cache, aux
+
 
 def decoder_forward(layers_params: Any, x, cfg, sharder: Sharder, positions,
                     mode: str = "train", cache: Any = None, cur_len=None,
@@ -232,82 +279,17 @@ def decoder_forward(layers_params: Any, x, cfg, sharder: Sharder, positions,
                     ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
     """Run the full decoder stack.  Returns (x, new_cache, aux_loss)."""
     seq_capacity = seq_capacity or x.shape[1]
-    hybrid = cfg.family == "hybrid"
-    period = cfg.attn_every if hybrid else 1
 
-    def one_layer(pos):
+    def layer(pos):
         def fn(x, p, c):
             return apply_layer(p, x, cfg, pos, sharder, positions, mode,
                                c, cur_len, chunk, seq_capacity)
         return fn
 
-    n_steps = (cfg.n_layers // period)
-
-    if mode == "decode":
-        # Decode carries the WHOLE cache through the scan and updates it
-        # in place with dynamic_update_index: XLA aliases while-loop
-        # carries, so the multi-GB cache exists ONCE.  (Passing it as
-        # scan xs/ys double-buffers it — measured +12.8 GB/device at
-        # deepseek decode_32k scale.)
-        def ds(tree_, li):
-            return jax.tree.map(
-                lambda c: jax.lax.dynamic_index_in_dim(c, li, 0,
-                                                       keepdims=False),
-                tree_)
-
-        def dus(tree_, new, li):
-            return jax.tree.map(
-                lambda c, n: jax.lax.dynamic_update_index_in_dim(
-                    c, n.astype(c.dtype), li, 0), tree_, new)
-
-        def dbody(carry, lp):
-            x, aux, cache_all, li = carry
-            if hybrid:
-                for pos in range(period):
-                    lc = ds(cache_all[pos], li)
-                    x, nc, a = one_layer(pos)(x, lp[pos], lc)
-                    aux = aux + a
-                    cache_all = (cache_all[:pos]
-                                 + (dus(cache_all[pos], nc, li),)
-                                 + cache_all[pos + 1:])
-            else:
-                lc = ds(cache_all, li)
-                x, nc, a = one_layer(0)(x, lp, lc)
-                aux = aux + a
-                cache_all = dus(cache_all, nc, li)
-            return (x, aux, cache_all, li + 1), None
-
-        (x, aux, cache, _), _ = jax.lax.scan(
-            dbody, (x, jnp.zeros((), jnp.float32), cache, 0),
-            layers_params, length=n_steps)
-        return x, cache, aux
-
-    def body2(carry, xs):
-        x, aux = carry
-        lp = xs
-        if hybrid:
-            ncs = []
-            for pos in range(period):
-                fn = one_layer(pos)
-                if mode == "train":
-                    fn = jax.checkpoint(fn)
-                x, nc, a = fn(x, lp[pos], None)
-                aux = aux + a
-                ncs.append(nc)
-            ys = tuple(ncs) if mode != "train" else 0.0
-        else:
-            fn = one_layer(0)
-            if mode == "train":
-                fn = jax.checkpoint(fn)
-            x, nc, a = fn(x, lp, None)
-            aux = aux + a
-            ys = nc if mode != "train" else 0.0
-        return (x, aux), ys
-
-    (x, aux), caches = jax.lax.scan(body2, (x, jnp.zeros((), jnp.float32)),
-                                    layers_params, length=n_steps)
-    new_cache = caches if mode != "train" else None
-    return x, new_cache, aux
+    layers = (tuple(layer(pos) for pos in range(cfg.attn_every))
+              if cfg.family == "hybrid" else layer(0))
+    return scan_layers(layers, layers_params, x, mode, cache,
+                       aux=jnp.zeros((), jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -346,15 +328,15 @@ def make_positions(cfg, b: int, s: int, n_vis: int = 0, offset: int = 0):
 
 def lm_apply(params: Dict, batch: Dict, cfg, sharder: Sharder = IDENTITY_SHARDER,
              mode: str = "train", cache: Any = None, cur_len=None,
-             chunk: int = 2048, seq_capacity: int = 0,
-             compute_dtype=COMPUTE_DTYPE) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
-    """Unified LM entry.  Returns (logits, new_cache, aux_loss).
+             chunk: int = 2048, seq_capacity: int = 0
+             ) -> Tuple[jnp.ndarray, Any, jnp.ndarray]:
+    """Unified LM entry, computing in the dtype of ``params``.  Returns
+    (logits, new_cache, aux_loss).
 
     train  : logits (b, s, Vp)
     prefill: logits (b, 1, Vp) at the last position, + cache
     decode : logits (b, 1, Vp), + updated cache
     """
-    params = cast(params, compute_dtype)
     tokens = batch["tokens"]
     b = tokens.shape[0]
     embed_pos = batch.get("positions")

@@ -21,11 +21,11 @@ def build_prefill_step(model: Model, sharder: Sharder = IDENTITY_SHARDER,
     return prefill_step
 
 
-def build_decode_step(model: Model, sharder: Sharder = IDENTITY_SHARDER,
-                      sample: str = "greedy") -> Callable:
+def build_decode_step(model: Model, sharder: Sharder = IDENTITY_SHARDER
+                      ) -> Callable:
     """decode_step(params, batch) with batch = {tokens, cache, cur_len}.
 
-    Returns (next_tokens (b, 1), logits, new_cache).  The cache is
+    Returns (greedy next_tokens (b, 1), logits, new_cache).  The cache is
     functionally updated; jit callers should donate it.
     """
     def decode_step(params, batch):

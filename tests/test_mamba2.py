@@ -77,15 +77,13 @@ def test_hybrid_cache_path_matches_forward_in_f32(arch):
     S, n0 = 24, 10
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, S), 0,
                               cfg.vocab_size)
-    f32 = jnp.float32
     full, _, _ = jax.jit(lambda p, t: tf.lm_apply(
-        p, {"tokens": t}, cfg, mode="train", compute_dtype=f32))(params, toks)
+        p, {"tokens": t}, cfg, mode="train"))(params, toks)
     _, cache, _ = jax.jit(lambda p, t: tf.lm_apply(
-        p, {"tokens": t}, cfg, mode="prefill", seq_capacity=S,
-        compute_dtype=f32))(params, toks[:, :n0])
+        p, {"tokens": t}, cfg, mode="prefill", seq_capacity=S))(
+        params, toks[:, :n0])
     dec = jax.jit(lambda p, t, c, n: tf.lm_apply(
-        p, {"tokens": t}, cfg, mode="decode", cache=c, cur_len=n,
-        compute_dtype=f32))
+        p, {"tokens": t}, cfg, mode="decode", cache=c, cur_len=n))
     full = np.asarray(full)
     worst = 0.0
     for i in range(n0, S):

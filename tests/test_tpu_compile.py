@@ -78,8 +78,9 @@ def test_kernel_compiles_for_v5e(one_chip, name):
 
 
 def _decode_step_bytes(one_chip, model, slots, capacity):
-    """What the compiler says the server's decode step holds, f32 params
-    as ``BatchServer`` holds them."""
+    """What the compiler says the server's decode step holds, compiled on
+    the caller's f32 params: an upper bound, as ``BatchServer`` holds
+    them cast once to bf16."""
     def on_chip(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=one_chip), tree)
